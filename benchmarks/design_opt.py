@@ -21,7 +21,9 @@ import subprocess
 import sys
 
 from benchmarks.common import emit
-from repro.launch.mesh import HBM_BW, ICI_BW_PER_LINK
+from repro.launch.mesh import V5E, peaks
+
+CHIP = peaks(V5E)            # the modeled chip, not the host
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
@@ -95,8 +97,8 @@ print(f"RESULT {t_base:.1f} {t_two:.1f} {t_batch:.1f} {t_lut:.1f}")
 def analytic_ladder(dim_bytes: int = 512, pooling: int = 32, chips: int = 16):
     """Per-chip service time (ns) per bag under the four designs."""
     row = dim_bytes
-    hbm = HBM_BW
-    ici = ICI_BW_PER_LINK * 2
+    hbm = CHIP.hbm_bw
+    ici = CHIP.ici_bw_per_link * 2
     # baseline: every Q and R row crosses the network to the requester
     base = pooling * 2 * row / ici + pooling * 2 * row / hbm
     # two-level: rows served from owner HBM; one pooled vector crosses ICI
@@ -127,7 +129,7 @@ def tt_analytic_ladder(
     w1 = d1 * rank * bytes_per_elem
     w2 = rank * d2 * rank * bytes_per_elem
     w3 = rank * d3 * bytes_per_elem
-    hbm, ici = HBM_BW, ICI_BW_PER_LINK * 2
+    hbm, ici = CHIP.hbm_bw, CHIP.ici_bw_per_link * 2
     row_out = dim * bytes_per_elem
     base = pooling * (w1 + w2 + w3) / ici + pooling * (w1 + w2 + w3) / hbm
     two = pooling * (w1 + w2 + w3) / hbm + row_out / ici
@@ -155,7 +157,10 @@ def run() -> None:
     emit("design_opt/tt_analytic_hot_tier", 0.0,
          f"{th * 1e9:.1f}ns/bag speedup={tb / th:.2f}x (paper TT-Rec: 2.15x)")
 
+    # The child measures an 8-device HOST mesh by design: pin it to the CPU
+    # so it never competes with this process for an accelerator.
     env = dict(os.environ)
+    env["JAX_PLATFORMS"] = "cpu"
     env["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
     env["PYTHONPATH"] = os.path.join(ROOT, "src")
     out = subprocess.run(
@@ -164,8 +169,10 @@ def run() -> None:
     )
     line = [l for l in out.stdout.splitlines() if l.startswith("RESULT")]
     if not line:
-        emit("design_opt/measured", 0.0, f"FAILED: {out.stderr[-200:]}")
-        return
+        raise RuntimeError(
+            f"design_opt host-mesh worker failed (rc={out.returncode}): "
+            f"{out.stderr[-2000:]}"
+        )
     t_base, t_two, t_batch, t_lut = map(float, line[0].split()[1:])
     emit("design_opt/measured_gspmd_baseline", t_base, "8-dev host mesh, 4 bags")
     emit("design_opt/measured_two_level", t_two, f"speedup={t_base / t_two:.2f}x")

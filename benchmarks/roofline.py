@@ -34,12 +34,11 @@ import json
 import os
 
 from benchmarks.common import emit
-from repro.launch.mesh import (
-    DCN_BW, HBM_BW, ICI_BW_PER_LINK, PEAK_FLOPS_BF16,
-)
+from repro.launch.mesh import V5E, peaks
 from repro.obs import attribution as obs_attribution
 
-ICI_BW = 2 * ICI_BW_PER_LINK     # bidirectional ring on one torus dimension
+CHIP = peaks(V5E)                # the dry-run's target chip, not the host
+ICI_BW = 2 * CHIP.ici_bw_per_link    # bidirectional ring on one torus dimension
 
 
 def load_records(dryrun_dir: str = "experiments/dryrun") -> list[dict]:
@@ -64,14 +63,14 @@ def terms(rec: dict) -> dict | None:
         # split by attributing all-reduce wire with g==2 proportionally.
         dcn_share = 0.0
         if dcn_share > 0:
-            wire_bw = 1.0 / ((1 - dcn_share) / ICI_BW + dcn_share / DCN_BW)
+            wire_bw = 1.0 / ((1 - dcn_share) / ICI_BW + dcn_share / CHIP.dcn_bw)
     # bytes/flops -> seconds via the shared serving-attribution pricing
     t = obs_attribution.model_terms(
         flops=h["flops"], hbm_bytes=h["bytes"], wire_bytes=wire,
-        peak_flops=PEAK_FLOPS_BF16, hbm_bw=HBM_BW, wire_bw=wire_bw,
+        peak_flops=CHIP.flops_bf16, hbm_bw=CHIP.hbm_bw, wire_bw=wire_bw,
     )
     step = t["step_s"]
-    mfu = rec["model_flops"] / chips / PEAK_FLOPS_BF16 / step
+    mfu = rec["model_flops"] / chips / CHIP.flops_bf16 / step
     return {
         **t,
         "mfu_model": mfu,

@@ -11,6 +11,7 @@ import sys
 import time
 
 from benchmarks import common
+from repro.launch import compile_cache
 from benchmarks import (
     autotune, cache_sim, collision_sweep, design_opt, locality, roofline,
     serve_qps, serve_storm, traffic, tt_sweep,
@@ -42,6 +43,7 @@ def main() -> int:
                     help="seed for suites that take one (stamped into their "
                          "JSON rows so any row reproduces its run)")
     args = ap.parse_args()
+    compile_cache.enable()
     names = args.only.split(",") if args.only else list(SUITES)
     print("name,us_per_call,derived")
     failed = []

@@ -117,6 +117,6 @@ def run(tiny: bool = False) -> None:
             if bn.achieved_gbps is not None:
                 detail += f" achieved={bn.achieved_gbps:.2f}GB/s"
             if bn.modeled_gbps is not None:
-                detail += f" modeled={bn.modeled_gbps:.2f}GB/s"
+                detail += f" v5e_modeled={bn.modeled_gbps:.2f}GB/s"
         detail += " fenced=1"
         emit(f"serve_qps/{arch}_bottleneck", 0.0, detail)

@@ -23,14 +23,12 @@ import shutil
 import jax
 import numpy as np
 
-from repro.distributed import jax_compat
-
 
 def _flatten_with_names(tree):
     leaves, treedef = jax.tree.flatten(tree)
     paths = [
         "/".join(str(getattr(k, "key", getattr(k, "idx", k))) for k in path)
-        for path, _ in jax_compat.tree_flatten_with_path(tree)[0]
+        for path, _ in jax.tree.flatten_with_path(tree)[0]
     ]
     return leaves, paths, treedef
 

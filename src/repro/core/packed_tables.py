@@ -14,7 +14,9 @@ module builds that pass for the TPU:
 * ``pack_params`` — the device-side concatenation (+ one trailing all-zero
   row per streamed buffer: accesses that must contribute nothing — ragged
   bag tails, non-owned rows on a shard — are *routed to the zero row*
-  instead of masked, so the kernel needs no predication);
+  instead of masked, so the kernel needs no predication).  The streamed
+  buffer is built directly in the megakernel's row layout (``stream_view``),
+  so a serving session pays for that layout once, at pack time;
 * ``pack_indices`` — logical (B, T, K) bag indices -> globally-offset int32
   streams, vectorized over all tables at once (the per-table Python loop
   becomes index arithmetic);
@@ -215,9 +217,20 @@ def combiner_scale(bags: Sequence[BagConfig], dtype) -> jax.Array:
 def concat_with_zero(parts: Sequence[jax.Array], dtype) -> jax.Array:
     """Row-concatenate buffers and append one all-zero row (the routing sink
     for accesses that must contribute nothing)."""
-    width = parts[0].shape[1]
-    zero = jnp.zeros((1, width), dtype)
+    zero = jnp.zeros((1, *parts[0].shape[1:]), dtype)
     return jnp.concatenate([p.astype(dtype) for p in parts] + [zero], axis=0)
+
+
+def stream_view(big: jax.Array, layout: PackedLayout) -> jax.Array:
+    """A streamed buffer in the megakernel's row layout: ``(rows, 1, dim)``
+    for dense / QR (``kernels.blocks.row_view``), ``(rows, r, d2*r)`` for the
+    TT middle core (``kernels.tt_gather.g2_view``).  Same row-major bytes as
+    the flat ``(rows, width)`` buffer; the oracles take either."""
+    from repro.kernels import blocks, tt_gather
+
+    if layout.kind == "tt":
+        return tt_gather.g2_view(big, layout.tt_dims[3])
+    return blocks.row_view(big)
 
 
 def pack_params(tables: Sequence[dict], layout: PackedLayout, *, dtype=None) -> dict:
@@ -226,25 +239,23 @@ def pack_params(tables: Sequence[dict], layout: PackedLayout, *, dtype=None) -> 
     Streamed buffers (big table, QR R LUT) get one trailing all-zero row so
     masked accesses can be routed instead of predicated.  Outer TT cores are
     packed without a zero row — a zero G2 row already nulls the contraction.
+    The big buffer is concatenated straight into its ``stream_view``.
     """
+    key = big_key(layout.kind)
+    dtype = dtype or tables[0][key].dtype
+    big = concat_with_zero(
+        [stream_view(t[key], layout) for t in tables], dtype
+    )
+    assert big.shape[0] == layout.total_rows + 1, (big.shape, layout.rows_per_table)
     if layout.kind == "qr":
-        dtype = dtype or tables[0]["q"].dtype
-        q = concat_with_zero([t["q"] for t in tables], dtype)
         r = concat_with_zero([t["r"] for t in tables], dtype)
-        assert q.shape[0] == layout.total_rows + 1, (q.shape, layout.rows_per_table)
         assert r.shape[0] == layout.total_small + 1
-        return {"q": q, "r": r}
+        return {"q": big, "r": r}
     if layout.kind == "tt":
-        dtype = dtype or tables[0]["g2"].dtype
-        g2 = concat_with_zero([t["g2"] for t in tables], dtype)
         g1 = jnp.concatenate([t["g1"].astype(dtype) for t in tables], axis=0)
         g3 = jnp.concatenate([t["g3"].astype(dtype) for t in tables], axis=0)
-        assert g2.shape[0] == layout.total_rows + 1
-        return {"g1": g1, "g2": g2, "g3": g3}
-    dtype = dtype or tables[0]["table"].dtype
-    table = concat_with_zero([t["table"] for t in tables], dtype)
-    assert table.shape[0] == layout.total_rows + 1
-    return {"table": table}
+        return {"g1": g1, "g2": big, "g3": g3}
+    return {"table": big}
 
 
 # ---------------------------------------------------------------------------

@@ -36,7 +36,6 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 from repro.core import hashing
 from repro.core.embedding_bag import BagConfig
 from repro.core.qr_embedding import EmbeddingConfig
-from repro.distributed import jax_compat
 
 # Q tables are padded so every potential model-axis size divides the row count.
 ROW_PAD = 128
@@ -265,6 +264,7 @@ def packed_local_partial(
     axis: str = "model",
     hot_tiers: Sequence[dict] | None = None,
     comm_free: Sequence[bool] | None = None,
+    exec_mode: str = "auto",
     interpret: bool | None = None,
 ) -> jax.Array:
     """Every table's local pooled partial in ONE megakernel dispatch.
@@ -311,7 +311,10 @@ def packed_local_partial(
         hot_sizes = [int(h.shape[0]) for h in hots]
         parts += hots
     width = int(segs[0].shape[1])
-    big = packed_tables.concat_with_zero(parts, compute)
+    layout = packed_tables.layout_for(bags)
+    big = packed_tables.concat_with_zero(
+        [packed_tables.stream_view(p, layout) for p in parts], compute
+    )
     seg_sizes = [int(s.shape[0]) for s in segs]
     seg_off = np.cumsum([0] + seg_sizes)
     hot_off = seg_off[-1] + np.cumsum([0] + hot_sizes)
@@ -357,7 +360,7 @@ def packed_local_partial(
         out = ops.packed_multi_pooled(
             {"q": big, "r": r_packed, "cache": cache},
             {"q_idx": route_big(q_idx), "slot": miss, "r_idx": r_stream},
-            kind="qr", interpret=interpret,
+            kind="qr", exec_mode=exec_mode, interpret=interpret,
         )
     elif kind == "tt":
         spec = emb0.tt_spec
@@ -378,13 +381,13 @@ def packed_local_partial(
                 "slot": miss,
             },
             kind="tt", dims=(spec.d1, spec.d2, spec.d3, spec.rank),
-            interpret=interpret,
+            exec_mode=exec_mode, interpret=interpret,
         )
     else:
         out = ops.packed_multi_pooled(
             {"table": big, "cache": cache},
             {"idx": route_big(indices), "slot": miss},
-            kind="dense", interpret=interpret,
+            kind="dense", exec_mode=exec_mode, interpret=interpret,
         )
 
     scale = packed_tables.combiner_scale(bags, out.dtype)
@@ -479,7 +482,7 @@ def build_token_embed(
 
     @jax.jit
     def fn(params, idx, tier=None):
-        return jax_compat.shard_map(
+        return jax.shard_map(
             local_fn, mesh=mesh, in_specs=in_specs,
             out_specs=P(batch_axis, None, None), check_vma=False,
         )(params, idx, tier)
@@ -520,7 +523,7 @@ def token_embed_inline(params: dict, idx: jax.Array, cfg: EmbeddingConfig,
         part = qr_token_partial(q_shard, r_full, idx_l, plan, axis=row_axis)
         return jax.lax.psum(part, row_axis)
 
-    return jax_compat.shard_map(
+    return jax.shard_map(
         local_fn,
         mesh=mesh,
         in_specs=(P(row_axis, None), P(), P(batch_spec, None)),

@@ -15,8 +15,6 @@ from typing import Mapping, Sequence
 import jax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from repro.distributed import jax_compat
-
 _state = threading.local()
 
 
@@ -139,7 +137,7 @@ def shardings_for_tree(mesh: Mesh, tree, axes_tree, rules: Mapping):
         flat_axes[path] = axes
 
     # walk axes tree by path so arrays and axes may differ in leaf typing
-    for path, axes in jax_compat.tree_flatten_with_path(
+    for path, axes in jax.tree.flatten_with_path(
         axes_tree, is_leaf=_is_axes_tuple
     )[0]:
         record(tuple(str(p) for p in path), axes)
@@ -150,7 +148,7 @@ def shardings_for_tree(mesh: Mesh, tree, axes_tree, rules: Mapping):
             return NamedSharding(mesh, P())
         return NamedSharding(mesh, resolve_spec(mesh, x.shape, axes, rules))
 
-    flat, treedef = jax_compat.tree_flatten_with_path(tree)
+    flat, treedef = jax.tree.flatten_with_path(tree)
     return jax.tree.unflatten(treedef, [leaf(tuple(str(p) for p in pa), x) for pa, x in flat])
 
 

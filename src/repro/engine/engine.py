@@ -41,7 +41,6 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 from repro import obs
 from repro.core import embedding_bag, hashing, packed_tables
 from repro.core import sharded_embedding as SE
-from repro.distributed import jax_compat
 from repro.engine.plan import EmbeddingPlan, plan as _plan
 from repro.engine.spec import EngineSpec
 
@@ -51,8 +50,9 @@ from repro.engine.spec import EngineSpec
 # repeated sessions/benchmark repeats hit jax's compilation cache.
 # ---------------------------------------------------------------------------
 
-@functools.partial(jax.jit, static_argnames=("plan",))
-def _serve_gather_jit(packed, idx, slot, cache_rows, plan: EmbeddingPlan):
+@functools.partial(jax.jit, static_argnames=("plan", "interpret"))
+def _serve_gather_jit(packed, idx, slot, cache_rows, plan: EmbeddingPlan,
+                      interpret: bool | None = None):
     from repro.kernels import ops
 
     # Trace-time bump: a counter inside a jitted body counts *traces*, not
@@ -67,7 +67,7 @@ def _serve_gather_jit(packed, idx, slot, cache_rows, plan: EmbeddingPlan):
     pooled = ops.packed_multi_pooled(
         {**packed, "cache": cache}, streams,
         kind=layout.kind, dims=layout.tt_dims, exec_mode=plan.spec.exec_backend,
-        dim_block=plan.dim_block,
+        dim_block=plan.dim_block, interpret=interpret,
     )
     scale = packed_tables.combiner_scale(plan.bags, jnp.float32)
     return pooled * scale[None, :, None].astype(pooled.dtype)
@@ -141,7 +141,7 @@ class EmbeddingEngine:
             parts = SE.packed_local_partial(
                 tables, indices, bags, plans, axis=axis,
                 hot_tiers=hot_tiers, comm_free=cf if any(cf) else None,
-                interpret=interpret,
+                exec_mode=self.spec.exec_backend, interpret=interpret,
             )
             if len(psum_cols) == len(bags):
                 return jax.lax.psum(parts, axis)
@@ -209,13 +209,14 @@ class EmbeddingEngine:
             return {"g1": P(), "g2": P(row_axis, None), "g3": P()}
         return {"table": P(row_axis, None)}
 
-    def gnr(self, mesh: Mesh, *, hot: bool = False):
+    def gnr(self, mesh: Mesh, *, hot: bool = False, interpret=None):
         """Jitted global GnR over all tables — the end-to-end two-level scheme.
 
         Returned fn: ``fn(tables, indices (B, T, K), hot_tiers=None)`` ->
         (B, T, dim).  Plans carrying a duplication plan serve comm-free
         tables from local replicas (replicated in_specs, no psum); ``hot``
-        adds hot-tier specs on plain plans.
+        adds hot-tier specs on plain plans.  ``interpret`` as in
+        ``ops.packed_multi_pooled``.
         """
         obs.inc("engine/dispatch/gnr_build")
         spec = self.spec
@@ -228,7 +229,7 @@ class EmbeddingEngine:
         def local_fn(tables, indices, hot_tiers):
             return self.forward_partial(
                 tables, indices, num_shards=nsh, hot_tiers=hot_tiers,
-                axis=row_axis,
+                axis=row_axis, interpret=interpret,
             )
 
         in_specs = (
@@ -241,7 +242,7 @@ class EmbeddingEngine:
 
         @jax.jit
         def fn(tables, indices, hot_tiers=None):
-            return jax_compat.shard_map(
+            return jax.shard_map(
                 local_fn, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
                 check_vma=False,
             )(tables, indices, hot_tiers)
@@ -270,7 +271,7 @@ class EmbeddingEngine:
         def local_fn(tabs, idx):
             return self.forward_partial(tabs, idx, num_shards=nsh, axis=row_axis)
 
-        return jax_compat.shard_map(
+        return jax.shard_map(
             local_fn,
             mesh=mesh,
             in_specs=(

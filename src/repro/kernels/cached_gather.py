@@ -17,9 +17,8 @@ SRAM while the remaining rows stream from DRAM.  TPU realization:
 * accumulation is fp32 in a VMEM output block revisited across the K steps
   (bank-group MAC + register file), exactly like ``gnr_bag``.
 
-Two variants: ``cached_bag`` (dense / big-table-only) and ``cached_qr_bag``
-(fused with the VMEM-resident R LUT, so one bag element costs at most one
-HBM row — and zero on a cache hit).
+Block layout: see ``repro.kernels.blocks`` (streamed rows and outputs are
+``(rows, 1, dim)`` views; the cache block and R LUT stay 2-D f32).
 """
 
 from __future__ import annotations
@@ -29,53 +28,39 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
-from jax.experimental.pallas import tpu as pltpu
+
+from repro.kernels.blocks import (
+    accumulate, f32_rows, resident, row_spec, row_view, run_bags, step,
+)
 
 DEFAULT_DIM_BLOCK = 512
 
 
-def _cached_kernel(idx_ref, slot_ref, row_ref, cache_ref, out_ref):
-    b, k = pl.program_id(0), pl.program_id(1)
-    s = slot_ref[b, k]
-    hit = s >= 0
-    cached = cache_ref[jnp.maximum(s, 0), :][None, :].astype(jnp.float32)
-    streamed = row_ref[...].astype(jnp.float32)
-    row = jnp.where(hit, cached, streamed)
+def _cached_row(pos, slot_ref, row_ref, cache_ref):
+    s = slot_ref[pos]
+    cached = cache_ref[pl.ds(jnp.maximum(s, 0), 1), :]
+    return jnp.where(s >= 0, cached, row_ref[...].astype(jnp.float32))
 
-    @pl.when(k == 0)
-    def _init():
-        out_ref[...] = row
 
-    @pl.when(k > 0)
-    def _acc():
-        out_ref[...] = out_ref[...] + row
+def _cached_kernel(idx_ref, slot_ref, row_ref, cache_ref, out_ref, *, k_steps):
+    accumulate(out_ref, _cached_row(step(k_steps), slot_ref, row_ref, cache_ref))
 
 
 def _cached_qr_kernel(q_idx_ref, slot_ref, r_idx_ref, row_ref, cache_ref,
-                      r_lut_ref, out_ref):
-    b, k = pl.program_id(0), pl.program_id(1)
-    s = slot_ref[b, k]
-    hit = s >= 0
-    cached = cache_ref[jnp.maximum(s, 0), :][None, :].astype(jnp.float32)
-    streamed = row_ref[...].astype(jnp.float32)
-    row = jnp.where(hit, cached, streamed)
-    row = row + r_lut_ref[r_idx_ref[b, k], :][None, :].astype(jnp.float32)
-
-    @pl.when(k == 0)
-    def _init():
-        out_ref[...] = row
-
-    @pl.when(k > 0)
-    def _acc():
-        out_ref[...] = out_ref[...] + row
+                      r_lut_ref, out_ref, *, k_steps):
+    pos = step(k_steps)
+    row = _cached_row(pos, slot_ref, row_ref, cache_ref)
+    accumulate(out_ref, row + r_lut_ref[pl.ds(r_idx_ref[pos], 1), :])
 
 
-def _stream_spec(bd: int):
-    # Misses DMA row idx[b,k]; hits pin the stream to block 0 so consecutive
+def _stream_spec(bd: int, k_steps: int) -> pl.BlockSpec:
+    # Misses DMA row idx[b,k]; hits pin the stream to row 0 so consecutive
     # hits revisit the same block and Pallas skips the fetch.
-    return pl.BlockSpec(
-        (1, bd), lambda b, k, j, idx, slot, *_: (jnp.where(slot[b, k] >= 0, 0, idx[b, k]), j)
-    )
+    def row(b, k, idx, slot, *_):
+        pos = b * k_steps + k
+        return jnp.where(slot[pos] >= 0, 0, idx[pos])
+
+    return row_spec(bd, row)
 
 
 @functools.partial(jax.jit, static_argnames=("dim_block", "interpret"))
@@ -90,32 +75,21 @@ def cached_bag(
 ) -> jax.Array:
     """Cached pooled bag: out[b] = Σ_k (slot[b,k] >= 0 ? C[slot] : T[idx]).
 
-    table: (rows, dim) in HBM; cache: (slots, dim) VMEM-resident (the staged
-    block — same dtype as table); idx/slot: (B, K) int32.  Returns (B, dim)
-    in the table dtype (fp32 accumulation inside).
+    table: (rows, dim) or its ``row_view`` in HBM; cache: (slots, dim)
+    VMEM-resident (the staged block); idx/slot: (B, K) int32.  Returns
+    (B, dim) in the table dtype (fp32 accumulation inside).
     """
-    bsz, k_steps = idx.shape
-    dim = table.shape[1]
+    k_steps = idx.shape[1]
+    dim = table.shape[-1]
     bd = dim_block or min(dim, DEFAULT_DIM_BLOCK)
     assert dim % bd == 0, f"dim {dim} not divisible by dim_block {bd}"
-    assert cache.shape[1] == dim, (cache.shape, table.shape)
-
-    grid = (bsz, k_steps, dim // bd)
-    kernel = pl.pallas_call(
-        _cached_kernel,
-        grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=2,
-            grid=grid,
-            in_specs=[
-                _stream_spec(bd),
-                pl.BlockSpec((cache.shape[0], bd), lambda b, k, j, idx, slot: (0, j)),
-            ],
-            out_specs=pl.BlockSpec((1, bd), lambda b, k, j, idx, slot: (b, j)),
-        ),
-        out_shape=jax.ShapeDtypeStruct((bsz, dim), jnp.float32),
-        interpret=interpret,
+    cache = f32_rows(cache, dim)
+    out = run_bags(
+        functools.partial(_cached_kernel, k_steps=k_steps),
+        [idx, slot], [row_view(table), cache],
+        [_stream_spec(bd, k_steps), resident(cache.shape[0], bd)],
+        dim=dim, bd=bd, interpret=interpret,
     )
-    out = kernel(idx.astype(jnp.int32), slot.astype(jnp.int32), table, cache)
     return out.astype(table.dtype)
 
 
@@ -135,36 +109,23 @@ def cached_qr_bag(
     out[b] = Σ_k ( (slot >= 0 ? C[slot] : Q[q_idx]) + R[r_idx] ).
 
     The R LUT and the cache block are both VMEM-resident; only cache misses
-    touch HBM.  q_idx/slot/r_idx: (B, K) int32 -> (B, dim).
+    touch HBM.  q_table: (rows, dim) or its ``row_view``; q_idx/slot/r_idx:
+    (B, K) int32 -> (B, dim).
     """
-    bsz, k_steps = q_idx.shape
-    dim = q_table.shape[1]
+    k_steps = q_idx.shape[1]
+    dim = q_table.shape[-1]
     bd = dim_block or min(dim, DEFAULT_DIM_BLOCK)
     assert dim % bd == 0, f"dim {dim} not divisible by dim_block {bd}"
-    assert cache.shape[1] == dim and r_lut.shape[1] == dim
-
-    grid = (bsz, k_steps, dim // bd)
-    kernel = pl.pallas_call(
-        _cached_qr_kernel,
-        grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=3,
-            grid=grid,
-            in_specs=[
-                _stream_spec(bd),
-                pl.BlockSpec(
-                    (cache.shape[0], bd), lambda b, k, j, qi, sl, ri: (0, j)
-                ),
-                pl.BlockSpec(
-                    (r_lut.shape[0], bd), lambda b, k, j, qi, sl, ri: (0, j)
-                ),
-            ],
-            out_specs=pl.BlockSpec((1, bd), lambda b, k, j, qi, sl, ri: (b, j)),
-        ),
-        out_shape=jax.ShapeDtypeStruct((bsz, dim), jnp.float32),
-        interpret=interpret,
-    )
-    out = kernel(
-        q_idx.astype(jnp.int32), slot.astype(jnp.int32), r_idx.astype(jnp.int32),
-        q_table, cache, r_lut,
+    assert cache.shape[-1] == dim and r_lut.shape[-1] == dim
+    cache, r_lut = f32_rows(cache, dim), f32_rows(r_lut, dim)
+    out = run_bags(
+        functools.partial(_cached_qr_kernel, k_steps=k_steps),
+        [q_idx, slot, r_idx], [row_view(q_table), cache, r_lut],
+        [
+            _stream_spec(bd, k_steps),
+            resident(cache.shape[0], bd),
+            resident(r_lut.shape[0], bd),
+        ],
+        dim=dim, bd=bd, interpret=interpret,
     )
     return out.astype(q_table.dtype)

@@ -24,7 +24,10 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
-from jax.experimental.pallas import tpu as pltpu
+
+from repro.kernels.blocks import (
+    accumulate, f32_rows, resident, row_spec, row_view, run_bags, step,
+)
 
 DEFAULT_DIM_BLOCK = 512
 
@@ -32,31 +35,12 @@ DEFAULT_DIM_BLOCK = 512
 def _qr_kernel(q_idx_ref, r_idx_ref, q_row_ref, r_lut_ref, out_ref, *, k_steps):
     # out_ref is the fp32 VMEM accumulator (bank-group MAC register file);
     # it is revisited across the K grid steps of the same bag.
-    b, k = pl.program_id(0), pl.program_id(1)
-    row = q_row_ref[...].astype(jnp.float32)
-    r = r_idx_ref[b, k]
-    row = row + r_lut_ref[r, :][None, :].astype(jnp.float32)
-
-    @pl.when(k == 0)
-    def _init():
-        out_ref[...] = row
-
-    @pl.when(k > 0)
-    def _acc():
-        out_ref[...] = out_ref[...] + row
+    r_row = r_lut_ref[pl.ds(r_idx_ref[step(k_steps)], 1), :]
+    accumulate(out_ref, q_row_ref[...].astype(jnp.float32) + r_row)
 
 
-def _dense_kernel(idx_ref, row_ref, out_ref, *, k_steps):
-    k = pl.program_id(1)
-    row = row_ref[...].astype(jnp.float32)
-
-    @pl.when(k == 0)
-    def _init():
-        out_ref[...] = row
-
-    @pl.when(k > 0)
-    def _acc():
-        out_ref[...] = out_ref[...] + row
+def _dense_kernel(idx_ref, row_ref, out_ref):
+    accumulate(out_ref, row_ref[...].astype(jnp.float32))
 
 
 @functools.partial(jax.jit, static_argnames=("dim_block", "interpret"))
@@ -70,29 +54,20 @@ def gnr_bag(
     interpret: bool = False,
 ) -> jax.Array:
     """Pooled QR bag. q_idx/r_idx: (B, K) int32 -> out (B, D)."""
-    bsz, k_steps = q_idx.shape
-    dim = q_table.shape[1]
+    k_steps = q_idx.shape[1]
+    dim = q_table.shape[-1]
     bd = dim_block or min(dim, DEFAULT_DIM_BLOCK)
     assert dim % bd == 0, f"dim {dim} not divisible by dim_block {bd}"
-
-    grid = (bsz, k_steps, dim // bd)
-    kernel = pl.pallas_call(
+    r_lut = f32_rows(r_lut, dim)
+    out = run_bags(
         functools.partial(_qr_kernel, k_steps=k_steps),
-        grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=2,
-            grid=grid,
-            in_specs=[
-                pl.BlockSpec((1, bd), lambda b, k, j, qi, ri: (qi[b, k], j)),
-                pl.BlockSpec(
-                    (r_lut.shape[0], bd), lambda b, k, j, qi, ri: (0, j)
-                ),
-            ],
-            out_specs=pl.BlockSpec((1, bd), lambda b, k, j, qi, ri: (b, j)),
-        ),
-        out_shape=jax.ShapeDtypeStruct((bsz, dim), jnp.float32),
-        interpret=interpret,
+        [q_idx, r_idx], [row_view(q_table), r_lut],
+        [
+            row_spec(bd, lambda b, k, qi, ri: qi[b * k_steps + k]),
+            resident(r_lut.shape[0], bd),
+        ],
+        dim=dim, bd=bd, interpret=interpret,
     )
-    out = kernel(q_idx.astype(jnp.int32), r_idx.astype(jnp.int32), q_table, r_lut)
     return out.astype(q_table.dtype)
 
 
@@ -105,21 +80,13 @@ def gnr_bag_dense(
     interpret: bool = False,
 ) -> jax.Array:
     """Pooled dense bag (baseline: no weight sharing). idx: (B, K) -> (B, D)."""
-    bsz, k_steps = idx.shape
-    dim = table.shape[1]
+    k_steps = idx.shape[1]
+    dim = table.shape[-1]
     bd = dim_block or min(dim, DEFAULT_DIM_BLOCK)
     assert dim % bd == 0
-
-    grid = (bsz, k_steps, dim // bd)
-    kernel = pl.pallas_call(
-        functools.partial(_dense_kernel, k_steps=k_steps),
-        grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=1,
-            grid=grid,
-            in_specs=[pl.BlockSpec((1, bd), lambda b, k, j, i: (i[b, k], j))],
-            out_specs=pl.BlockSpec((1, bd), lambda b, k, j, i: (b, j)),
-        ),
-        out_shape=jax.ShapeDtypeStruct((bsz, dim), jnp.float32),
-        interpret=interpret,
+    out = run_bags(
+        _dense_kernel, [idx], [row_view(table)],
+        [row_spec(bd, lambda b, k, i: i[b * k_steps + k])],
+        dim=dim, bd=bd, interpret=interpret,
     )
-    return kernel(idx.astype(jnp.int32), table).astype(table.dtype)
+    return out.astype(table.dtype)

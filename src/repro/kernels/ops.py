@@ -9,8 +9,9 @@ Dispatch rules:
 * the lane tile (``dim_block``) is an explicit knob: callers may pass a tuned
   block (``repro.tune`` / ``EmbeddingPlan.dim_block``); ``None`` takes the
   heuristic ladder default.  Dims with no 8-aligned tile fall back to the
-  jnp reference (the assigned archs all have 128-aligned dims; tests exercise
-  the fallback too).
+  jnp reference in interpret mode only (tests exercise it); a compiled
+  kernel call with such a dim raises instead of silently leaving the device
+  path (the assigned archs all have 128-aligned dims).
 """
 
 from __future__ import annotations
@@ -31,6 +32,12 @@ def _interpret_default() -> bool:
     return jax.default_backend() != "tpu"
 
 
+def _flat(x: jax.Array) -> jax.Array:
+    """A packed buffer in its row view (``packed_tables.stream_view``) ->
+    the flat ``(rows, width)`` layout the jnp oracles take."""
+    return x.reshape(x.shape[0], -1)
+
+
 def _pick_dim_block(dim: int) -> int | None:
     """Heuristic lane-tile default — now sourced from the tuner's knob space
     (``repro.tune.knobs``), same ladder: largest of 512/256/128 dividing dim,
@@ -40,11 +47,20 @@ def _pick_dim_block(dim: int) -> int | None:
     return _knobs.default_dim_block(dim)
 
 
-def _resolve_dim_block(dim: int, dim_block: int | None) -> int | None:
+def _resolve_dim_block(
+    dim: int, dim_block: int | None, interpret: bool
+) -> int | None:
     """An explicit ``dim_block`` must be legal for ``dim``; ``None`` defers
-    to the heuristic ladder."""
+    to the heuristic ladder.  A dim with no tile takes the jnp reference in
+    interpret mode and raises for a compiled kernel."""
     if dim_block is None:
-        return _knobs.default_dim_block(dim)
+        bd = _knobs.default_dim_block(dim)
+        if bd is None and not interpret:
+            raise ValueError(
+                f"dim {dim} has no 8-aligned lane tile: the compiled kernel "
+                "cannot run it and there is no device fallback"
+            )
+        return bd
     valid = _knobs.valid_dim_blocks(dim)
     if dim_block not in valid:
         raise ValueError(
@@ -65,8 +81,8 @@ def qr_lookup(
 ) -> jax.Array:
     """Fused QR reconstruction for any index shape: (...,) -> (..., D)."""
     interpret = _interpret_default() if interpret is None else interpret
-    dim = q_table.shape[1]
-    bd = _resolve_dim_block(dim, dim_block)
+    dim = q_table.shape[-1]
+    bd = _resolve_dim_block(dim, dim_block, interpret)
     if bd is None:
         return ref.qr_lookup_ref(q_table, r_lut, q_idx, r_idx)
     shape = q_idx.shape
@@ -88,8 +104,8 @@ def gnr_pooled(
 ) -> jax.Array:
     """Pooled QR bag for index shape (..., K) -> (..., D)."""
     interpret = _interpret_default() if interpret is None else interpret
-    dim = q_table.shape[1]
-    bd = _resolve_dim_block(dim, dim_block)
+    dim = q_table.shape[-1]
+    bd = _resolve_dim_block(dim, dim_block, interpret)
     if bd is None:
         return ref.gnr_bag_ref(q_table, r_lut, q_idx, r_idx)
     *lead, k = q_idx.shape
@@ -122,6 +138,7 @@ def tt_pooled(
     d1, d2, d3, _ = dims
     dim = d1 * d2 * d3
     if dim % 8:
+        _resolve_dim_block(dim, None, interpret)
         return ref.tt_bag_ref(g1, g2, g3, i1, i2, i3, dims=dims)
     *lead, k = i1.shape
     out = _tt.tt_bag(
@@ -224,8 +241,8 @@ def cached_pooled(
     from repro.kernels import cached_gather as _cg
 
     interpret = _interpret_default() if interpret is None else interpret
-    dim = table.shape[1]
-    bd = _resolve_dim_block(dim, dim_block)
+    dim = table.shape[-1]
+    bd = _resolve_dim_block(dim, dim_block, interpret)
     if bd is None:
         return ref.cached_bag_ref(table, cache, idx, slot)
     *lead, k = idx.shape
@@ -251,8 +268,8 @@ def cached_qr_pooled(
     from repro.kernels import cached_gather as _cg
 
     interpret = _interpret_default() if interpret is None else interpret
-    dim = q_table.shape[1]
-    bd = _resolve_dim_block(dim, dim_block)
+    dim = q_table.shape[-1]
+    bd = _resolve_dim_block(dim, dim_block, interpret)
     if bd is None:
         return ref.cached_qr_bag_ref(q_table, cache, r_lut, q_idx, slot, r_idx)
     *lead, k = q_idx.shape
@@ -286,10 +303,10 @@ def packed_dense_pooled(
     from repro.kernels import packed_gather as _pg
 
     interpret = _interpret_default() if interpret is None else interpret
-    dim = table.shape[1]
-    bd = _resolve_dim_block(dim, dim_block)
+    dim = table.shape[-1]
+    bd = _resolve_dim_block(dim, dim_block, interpret)
     if bd is None:
-        return ref.packed_bag_ref(table, cache, idx, slot)
+        return ref.packed_bag_ref(_flat(table), _flat(cache), idx, slot)
     *lead, k = idx.shape
     out = _pg.packed_bag(
         table, cache, idx.reshape(-1, k), slot.reshape(-1, k),
@@ -313,10 +330,12 @@ def packed_qr_pooled(
     from repro.kernels import packed_gather as _pg
 
     interpret = _interpret_default() if interpret is None else interpret
-    dim = q_table.shape[1]
-    bd = _resolve_dim_block(dim, dim_block)
+    dim = q_table.shape[-1]
+    bd = _resolve_dim_block(dim, dim_block, interpret)
     if bd is None:
-        return ref.packed_qr_bag_ref(q_table, cache, r_lut, q_idx, slot, r_idx)
+        return ref.packed_qr_bag_ref(
+            _flat(q_table), _flat(cache), r_lut, q_idx, slot, r_idx
+        )
     *lead, k = q_idx.shape
     out = _pg.packed_qr_bag(
         q_table, cache, r_lut,
@@ -345,7 +364,10 @@ def packed_tt_pooled(
     interpret = _interpret_default() if interpret is None else interpret
     d1, d2, d3, _ = dims
     if (d1 * d2 * d3) % 8:
-        return ref.packed_tt_bag_ref(g1, g2, g3, cache, i1, i2, i3, slot, dims=dims)
+        _resolve_dim_block(d1 * d2 * d3, None, interpret)
+        return ref.packed_tt_bag_ref(
+            g1, _flat(g2), g3, _flat(cache), i1, i2, i3, slot, dims=dims
+        )
     *lead, k = i1.shape
     out = _pg.packed_tt_bag(
         g1, g2, g3, cache,
@@ -380,7 +402,8 @@ def _packed_dense_diff_fwd(table, cache, idx, slot, interpret, dim_block=None):
 def _packed_dense_diff_bwd(interpret, dim_block, res, ct):
     table, cache, idx, slot = res
     _, vjp = jax.vjp(
-        lambda t, c: ref.packed_bag_ref(t, c, idx, slot), table, cache
+        lambda t, c: ref.packed_bag_ref(_flat(t), _flat(c), idx, slot),
+        table, cache,
     )
     dt, dc = vjp(ct.astype(table.dtype))
     return dt, dc, *_zero_idx(idx, slot)
@@ -405,7 +428,9 @@ def _packed_qr_diff_fwd(q, cache, r, q_idx, slot, r_idx, interpret,
 def _packed_qr_diff_bwd(interpret, dim_block, res, ct):
     q, cache, r, q_idx, slot, r_idx = res
     _, vjp = jax.vjp(
-        lambda a, c, b: ref.packed_qr_bag_ref(a, c, b, q_idx, slot, r_idx),
+        lambda a, c, b: ref.packed_qr_bag_ref(
+            _flat(a), _flat(c), b, q_idx, slot, r_idx
+        ),
         q, cache, r,
     )
     dq, dc, dr = vjp(ct.astype(q.dtype))
@@ -431,7 +456,7 @@ def _packed_tt_diff_bwd(dims, interpret, res, ct):
     g1, g2, g3, cache, i1, i2, i3, slot = res
     _, vjp = jax.vjp(
         lambda a, b, c, cc: ref.packed_tt_bag_ref(
-            a, b, c, cc, i1, i2, i3, slot, dims=dims
+            a, _flat(b), c, _flat(cc), i1, i2, i3, slot, dims=dims
         ),
         g1, g2, g3, cache,
     )
@@ -466,34 +491,34 @@ def packed_multi_pooled(
     ``"kernel"`` always runs the kernel (interpret on CPU — the serving
     driver's validation mode); ``"jnp"`` always the oracle.  The kernel path
     carries a reference-recompute vjp, so all modes are training-safe.
+    ``interpret=None`` compiles the kernel on TPU and interprets it
+    elsewhere; an explicit ``False`` with ``exec_mode="kernel"`` compiles it
+    whatever the backend (the described-chip compile tests).
     """
     use_kernel = {
         "auto": bool(interpret) or jax.default_backend() == "tpu",
         "kernel": True,
         "jnp": False,
     }[exec_mode]
+    if not use_kernel:                       # the oracles take flat rows
+        params = {k: _flat(v) for k, v in params.items()}
+    interp = _interpret_default() if interpret is None else bool(interpret)
     if kind == "qr":
         args = (params["q"], params["cache"], params["r"],
                 streams["q_idx"], streams["slot"], streams["r_idx"])
         if use_kernel:
-            return _packed_qr_diff(
-                *args, bool(interpret) or _interpret_default(), dim_block
-            )
+            return _packed_qr_diff(*args, interp, dim_block)
         return ref.packed_qr_bag_ref(*args)
     if kind == "tt":
         args = (params["g1"], params["g2"], params["g3"], params["cache"],
                 streams["i1"], streams["i2"], streams["i3"], streams["slot"])
         if use_kernel:
-            return _packed_tt_diff(
-                *args, dims, bool(interpret) or _interpret_default()
-            )
+            return _packed_tt_diff(*args, dims, interp)
         return ref.packed_tt_bag_ref(*args, dims=dims)
     if kind == "dense":
         args = (params["table"], params["cache"], streams["idx"], streams["slot"])
         if use_kernel:
-            return _packed_dense_diff(
-                *args, bool(interpret) or _interpret_default(), dim_block
-            )
+            return _packed_dense_diff(*args, interp, dim_block)
         return ref.packed_bag_ref(*args)
     raise ValueError(f"packed_multi_pooled: unsupported kind {kind!r}")
 
@@ -504,8 +529,8 @@ def gnr_pooled_dense(
 ) -> jax.Array:
     """Pooled dense bag for index shape (..., K) -> (..., D)."""
     interpret = _interpret_default() if interpret is None else interpret
-    dim = table.shape[1]
-    bd = _resolve_dim_block(dim, dim_block)
+    dim = table.shape[-1]
+    bd = _resolve_dim_block(dim, dim_block, interpret)
     if bd is None:
         return ref.dense_bag_ref(table, idx)
     *lead, k = idx.shape

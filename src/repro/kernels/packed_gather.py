@@ -40,9 +40,10 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
-from jax.experimental.pallas import tpu as pltpu
 
 from repro.kernels import cached_gather as _cg
+from repro.kernels import tt_gather as _tt
+from repro.kernels.blocks import accumulate, f32_rows, resident, run_bags, step
 
 # Budget for the VMEM-RESIDENT operands of one dispatch (packed cache block +
 # packed R LUT / TT outer cores; constant index maps keep them live across the
@@ -67,33 +68,24 @@ def _check_resident(**blocks) -> None:
 # ---------------------------------------------------------------------------
 
 def _packed_tt_kernel(
-    i1_ref, i2_ref, i3_ref, slot_ref,   # scalar-prefetched (G, K) streams
-    g2_row_ref,                          # (1, r*d2*r) streamed middle-core row
-    cache_ref,                           # (slots, r*d2*r) staged G2 rows (VMEM)
-    g1_ref,                              # (T*v1, d1*r) packed outer cores (VMEM)
-    g3_ref,                              # (T*v3, r*d3) packed outer cores (VMEM)
+    i1_ref, i2_ref, i3_ref, slot_ref,   # scalar-prefetched flat (G*K,) streams
+    g2_row_ref,                          # (r, d2*r) streamed middle-core row
+    cache_ref,                           # (slots, r, d2*r) staged G2 rows (f32)
+    g1_ref,                              # (T*v1, d1*r) packed outer cores (f32)
+    g3_ref,                              # (T*v3, r*d3) packed outer cores (f32)
     out_ref,                             # (1, d1*d2*d3) fp32 accumulator
     *,
-    d1: int, d2: int, d3: int, rank: int,
+    d1: int, d2: int, d3: int, rank: int, k_steps: int,
 ):
-    g, k = pl.program_id(0), pl.program_id(1)
-    s = slot_ref[g, k]
-    hit = s >= 0
-    cached = cache_ref[jnp.maximum(s, 0), :].astype(jnp.float32)
-    streamed = g2_row_ref[0, :].astype(jnp.float32)
-    m = jnp.where(hit, cached, streamed).reshape(rank, d2 * rank)
-    a = g1_ref[i1_ref[g, k], :].astype(jnp.float32).reshape(d1, rank)
-    t = jnp.dot(a, m, preferred_element_type=jnp.float32).reshape(d1 * d2, rank)
-    c = g3_ref[i3_ref[g, k], :].astype(jnp.float32).reshape(rank, d3)
-    row = jnp.dot(t, c, preferred_element_type=jnp.float32).reshape(1, d1 * d2 * d3)
-
-    @pl.when(k == 0)
-    def _init():
-        out_ref[...] = row
-
-    @pl.when(k > 0)
-    def _acc():
-        out_ref[...] = out_ref[...] + row
+    pos = step(k_steps)
+    s = slot_ref[pos]
+    m = jnp.where(s >= 0, cache_ref[jnp.maximum(s, 0)],
+                  g2_row_ref[...].astype(jnp.float32))
+    row = _tt.contract(
+        g1_ref[pl.ds(i1_ref[pos], 1), :], m, g3_ref[pl.ds(i3_ref[pos], 1), :],
+        d1=d1, d2=d2, d3=d3, rank=rank,
+    )
+    accumulate(out_ref, row)
 
 
 # ---------------------------------------------------------------------------
@@ -111,7 +103,8 @@ def packed_bag(
 ) -> jax.Array:
     """Packed dense megabag: out[g] = Σ_k (slot[g,k] >= 0 ? C[slot] : T[idx]).
 
-    table: (total_rows, dim) — ALL tables concatenated (+ trailing zero row);
+    table: (total_rows, dim) or its ``blocks.row_view`` — ALL tables
+    concatenated (+ trailing zero row);
     cache: (total_slots, dim) packed staged block; idx/slot: (G, K) int32
     with G = batch * num_tables and idx already globally offset.
 
@@ -141,7 +134,8 @@ def packed_qr_bag(
     """Packed QR megabag:
     out[g] = Σ_k ( (slot >= 0 ? C[slot] : Q[q_idx]) + R[r_idx] ).
 
-    q_table: (total_q_rows, dim) all Q tables packed (+ zero row); r_lut:
+    q_table: (total_q_rows, dim) or its ``blocks.row_view``, all Q tables
+    packed (+ zero row); r_lut:
     (total_r_rows, dim) all R LUTs packed (+ zero row), VMEM-resident as one
     block; q_idx/slot/r_idx: (G, K) globally-offset streams -> (G, dim).
     Kernel body = ``cached_gather.cached_qr_bag`` over the packed buffers
@@ -173,47 +167,43 @@ def packed_tt_bag(
 
     g1: (T*v1, d1*r) / g3: (T*v3, r*d3) — every table's outer cores packed and
     VMEM-resident (the bg-PIM SRAM pin, now shared by the whole model);
-    g2: (total_v2_rows, r*d2*r) packed middle cores (+ zero row); cache:
-    (total_slots, r*d2*r) staged G2 rows.  i1/i2/i3/slot: (G, K) globally
-    offset.  ``dims`` = (d1, d2, d3, rank), static.  Returns (G, d1*d2*d3).
+    g2: (total_v2_rows, r*d2*r) packed middle cores (+ zero row), flat or in
+    ``tt_gather.g2_view``; cache: the staged G2 rows, same width.
+    i1/i2/i3/slot: (G, K) globally offset.  ``dims`` = (d1, d2, d3, rank),
+    static.  Returns (G, d1*d2*d3).
     """
     d1, d2, d3, rank = dims
-    gsz, k_steps = i1.shape
+    k_steps = i1.shape[1]
     dim = d1 * d2 * d3
+    width = rank * d2 * rank
     assert g1.shape[1] == d1 * rank, (g1.shape, dims)
-    assert g2.shape[1] == rank * d2 * rank, (g2.shape, dims)
+    assert g2.size == g2.shape[0] * width, (g2.shape, dims)
     assert g3.shape[1] == rank * d3, (g3.shape, dims)
-    assert cache.shape[1] == g2.shape[1], (cache.shape, g2.shape)
+    assert cache.size == cache.shape[0] * width, (cache.shape, g2.shape)
     _check_resident(cache=cache, g1=g1, g3=g3)
+    cache = _tt.g2_view(cache, rank).astype(jnp.float32)
+    g1, g3 = f32_rows(g1, g1.shape[1]), f32_rows(g3, g3.shape[1])
 
-    grid = (gsz, k_steps)
-    kernel = pl.pallas_call(
-        functools.partial(_packed_tt_kernel, d1=d1, d2=d2, d3=d3, rank=rank),
-        grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=4,
-            grid=grid,
-            in_specs=[
-                # Streamed G2 row: misses DMA i2's packed row, hits pin block 0.
-                pl.BlockSpec(
-                    (1, g2.shape[1]),
-                    lambda g, k, i1, i2, i3, sl: (
-                        jnp.where(sl[g, k] >= 0, 0, i2[g, k]), 0
-                    ),
-                ),
-                pl.BlockSpec(
-                    (cache.shape[0], cache.shape[1]),
-                    lambda g, k, i1, i2, i3, sl: (0, 0),
-                ),
-                pl.BlockSpec(g1.shape, lambda g, k, i1, i2, i3, sl: (0, 0)),
-                pl.BlockSpec(g3.shape, lambda g, k, i1, i2, i3, sl: (0, 0)),
-            ],
-            out_specs=pl.BlockSpec((1, dim), lambda g, k, i1, i2, i3, sl: (g, 0)),
+    def g2_row(g, k, i1, i2, i3, sl):
+        # Streamed G2 row: misses DMA i2's packed row, hits pin row 0.
+        pos = g * k_steps + k
+        return jnp.where(sl[pos] >= 0, 0, i2[pos])
+
+    out = run_bags(
+        functools.partial(
+            _packed_tt_kernel, d1=d1, d2=d2, d3=d3, rank=rank, k_steps=k_steps
         ),
-        out_shape=jax.ShapeDtypeStruct((gsz, dim), jnp.float32),
-        interpret=interpret,
-    )
-    out = kernel(
-        i1.astype(jnp.int32), i2.astype(jnp.int32), i3.astype(jnp.int32),
-        slot.astype(jnp.int32), g2, cache, g1, g3,
+        [i1, i2, i3, slot],
+        [_tt.g2_view(g2, rank), cache, g1, g3],
+        [
+            _tt.g2_spec(rank, width, g2_row),
+            pl.BlockSpec(
+                cache.shape, lambda g, k, j, *_: (0, 0, 0),
+                pipeline_mode=pl.Buffered(1),
+            ),
+            resident(*g1.shape),
+            resident(*g3.shape),
+        ],
+        dim=dim, bd=dim, interpret=interpret,
     )
     return out.astype(g2.dtype)

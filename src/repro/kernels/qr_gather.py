@@ -24,16 +24,16 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
-from jax.experimental.pallas import tpu as pltpu
+
+from repro.kernels.blocks import f32_rows, resident, row_spec, row_view, run_bags
 
 # Lane-dim tile for wide embeddings; must divide dim or equal dim.
 DEFAULT_DIM_BLOCK = 512
 
 
 def _kernel(q_idx_ref, r_idx_ref, q_row_ref, r_lut_ref, out_ref):
-    n = pl.program_id(0)
-    r = r_idx_ref[n]
-    out_ref[...] = q_row_ref[...] + r_lut_ref[r, :][None, :]
+    r_row = r_lut_ref[pl.ds(r_idx_ref[pl.program_id(0)], 1), :]
+    out_ref[...] = q_row_ref[...].astype(jnp.float32) + r_row
 
 
 @functools.partial(
@@ -50,28 +50,24 @@ def qr_gather(
 ) -> jax.Array:
     """out[n, :] = q_table[q_idx[n], :] + r_lut[r_idx[n], :].
 
-    q_table: (Q, D) float; r_lut: (C, D) same dtype; q_idx/r_idx: (N,) int32.
+    q_table: (Q, D) float (or its ``row_view``); r_lut: (C, D) same dtype;
+    q_idx/r_idx: (N,) int32.  Each lookup is a bag of one (grid (N, 1,
+    dim tiles)): one Q row DMA'd from HBM plus one VMEM LUT row.
     """
-    n = q_idx.shape[0]
-    dim = q_table.shape[1]
+    dim = q_table.shape[-1]
     bd = dim_block or min(dim, DEFAULT_DIM_BLOCK)
     assert dim % bd == 0, f"dim {dim} not divisible by dim_block {bd}"
-
-    grid = (n, dim // bd)
-    kernel = pl.pallas_call(
+    r_lut = f32_rows(r_lut, dim)
+    out = run_bags(
         _kernel,
-        grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=2,  # q_idx, r_idx ride in SMEM ahead of DMAs
-            grid=grid,
-            in_specs=[
-                # One Q row per step, DMA'd from HBM by prefetched index.
-                pl.BlockSpec((1, bd), lambda i, j, qi, ri: (qi[i], j)),
-                # The LUT: same block every step -> stays resident in VMEM.
-                pl.BlockSpec((r_lut.shape[0], bd), lambda i, j, qi, ri: (0, j)),
-            ],
-            out_specs=pl.BlockSpec((1, bd), lambda i, j, qi, ri: (i, j)),
-        ),
-        out_shape=jax.ShapeDtypeStruct((n, dim), q_table.dtype),
-        interpret=interpret,
+        [q_idx[:, None], r_idx[:, None]],   # q_idx, r_idx ride in SMEM ahead of DMAs
+        [row_view(q_table), r_lut],
+        [
+            # One Q row per step, DMA'd from HBM by prefetched index.
+            row_spec(bd, lambda i, k, qi, ri: qi[i]),
+            # The LUT: same block every step -> stays resident in VMEM.
+            resident(r_lut.shape[0], bd),
+        ],
+        dim=dim, bd=bd, interpret=interpret,
     )
-    return kernel(q_idx.astype(jnp.int32), r_idx.astype(jnp.int32), q_table, r_lut)
+    return out.astype(q_table.dtype)

@@ -34,7 +34,6 @@ import jax.numpy as jnp
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from repro.configs import registry
-from repro.distributed import jax_compat
 from repro.configs.base import LM_SHAPES, ModelConfig, ShapeConfig
 from repro.distributed import sharding as SH
 from repro.launch import hlo_analysis
@@ -50,7 +49,7 @@ def param_counts(params_sds, cfg: ModelConfig) -> dict:
     """Total + MoE-active parameter counts from the abstract tree."""
     total = 0
     moe_total = 0
-    for path, leaf in jax_compat.tree_flatten_with_path(params_sds)[0]:
+    for path, leaf in jax.tree.flatten_with_path(params_sds)[0]:
         n = 1
         for d in leaf.shape:
             n *= d

@@ -53,6 +53,7 @@ from repro import obs
 from repro.configs import registry
 from repro.data import synthetic
 from repro.engine import EngineSpec, big_rows, big_subtable  # noqa: F401 (re-export)
+from repro.launch import compile_cache
 from repro.models import dlrm
 from repro.obs import attribution as obs_attribution
 from repro.obs import report as obs_report
@@ -141,12 +142,10 @@ def build_serve_state(cfg, *, shards: int, alpha: float, seed: int,
                       predicted_s=predicted_s, drift=drift)
 
 
-# Donate the consumed pooled buffer to the head on TPU (the double buffer's
-# memory hand-off); CPU has no donation support and would only warn.
-_HEAD_DONATE = (2,) if jax.default_backend() == "tpu" else ()
-
-
-@functools.partial(jax.jit, static_argnames=("cfg",), donate_argnums=_HEAD_DONATE)
+# The interaction + MLP head.  The pooled buffer is not donated: no output
+# has its (B, T, dim) shape, so the TPU compiler finds no use for it and only
+# warns ("Some donated buffers were not usable").
+@functools.partial(jax.jit, static_argnames=("cfg",))
 def _head_jit(params, dense, pooled, cfg):
     return dlrm.forward_from_pooled(params, dense, pooled, cfg)
 
@@ -503,6 +502,7 @@ def main(argv=None) -> int:
                          "session, e.g. 'period=8,frac=0.25' (rotations "
                          "every `period` batches)")
     args = ap.parse_args(argv)
+    compile_cache.enable()
 
     telemetry = bool(args.metrics_json or args.trace_out or args.slo
                      or args.report or args.flight_dir)

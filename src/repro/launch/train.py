@@ -31,6 +31,7 @@ from repro.checkpoint import checkpointer as ckpt
 from repro.configs import registry
 from repro.data import synthetic
 from repro.distributed import sharding as SH
+from repro.launch import compile_cache
 from repro.launch import mesh as mesh_mod
 from repro.train import optimizer as opt_mod
 from repro.train.train_step import make_dlrm_loss, make_train_step
@@ -111,7 +112,7 @@ def build(args):
     return cfg, params, opt_state, step_fn, make_batch
 
 
-def main(argv=None) -> int:
+def parse_args(argv=None) -> argparse.Namespace:
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", required=True)
     ap.add_argument("--smoke", action="store_true")
@@ -127,8 +128,12 @@ def main(argv=None) -> int:
     ap.add_argument("--ckpt-every", type=int, default=20)
     ap.add_argument("--mesh-shape", default=None, help="e.g. 2,4 for (data,model)")
     ap.add_argument("--log-every", type=int, default=5)
-    args = ap.parse_args(argv)
+    return ap.parse_args(argv)
 
+
+def main(argv=None) -> int:
+    args = parse_args(argv)
+    compile_cache.enable()
     cfg, params, opt_state, step_fn, make_batch = build(args)
     pipe = synthetic.Pipeline(
         make_batch=lambda seed, step: make_batch(args.batch, args.seq, seed=seed, step=step),

@@ -21,7 +21,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.configs.base import ModelConfig
-from repro.distributed import jax_compat, sharding
+from repro.distributed import sharding
 from repro.models.layers import _normal
 
 
@@ -173,7 +173,7 @@ def apply_moe(p, x, cfg: ModelConfig, *, row_axis: str = "model"):
         )
         return jax.lax.psum(out.reshape(xl.shape), row_axis)
 
-    out = jax_compat.shard_map(
+    out = jax.shard_map(
         local_fn,
         mesh=mesh,
         in_specs=(

@@ -48,9 +48,13 @@ SCHEMA = "stage-attribution/v1"
 # ---------------------------------------------------------------------------
 
 def _hw():
-    from repro.launch.mesh import HBM_BW, ICI_BW_PER_LINK, PEAK_FLOPS_BF16
+    """The modeled chip: the v5e's published peaks, named explicitly because
+    the attribution prices a serving session as if it ran there, whatever
+    device measured it."""
+    from repro.launch.mesh import V5E, peaks
 
-    return PEAK_FLOPS_BF16, HBM_BW, 2 * ICI_BW_PER_LINK
+    p = peaks(V5E)
+    return p.flops_bf16, p.hbm_bw, 2 * p.ici_bw_per_link
 
 
 def model_terms(*, flops: float = 0.0, hbm_bytes: float = 0.0,
@@ -118,15 +122,16 @@ def analytic_cost_model(backend: str = "packed") -> KernelCostModel:
     """A :class:`KernelCostModel` priced from the chip constants instead of a
     fit: dispatch at the tuner's launch-overhead estimate, bytes at HBM
     bandwidth, comm at the ICI wire rate (tiles free).  Used when a serving
-    session has no fitted tuner — attribution still reports modeled GB/s."""
-    from repro.launch.mesh import HBM_BW, ICI_BW_PER_LINK
+    session has no fitted tuner — attribution still reports modeled GB/s
+    (v5e-modeled, see ``_hw``)."""
     from repro.tune.tuner import DISPATCH_OVERHEAD_S
 
+    _, hbm_bw, wire_bw = _hw()
     coef = {
         "dispatches": DISPATCH_OVERHEAD_S,
-        "hbm_bytes": 1.0 / HBM_BW,
+        "hbm_bytes": 1.0 / hbm_bw,
         "row_tiles": 0.0,
-        "comm_bytes": 1.0 / (2 * ICI_BW_PER_LINK),
+        "comm_bytes": 1.0 / wire_bw,
     }
     return KernelCostModel(
         coef=tuple(coef[f] for f in FEATURES), backend=backend,

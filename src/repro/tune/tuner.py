@@ -362,7 +362,7 @@ def _hlo_sample(spec, knobs: Knobs, trace, batch: int) -> float:
     from repro import engine as engine_mod
     from repro.core import embedding_bag as EB
     from repro.launch import hlo_analysis
-    from repro.launch.mesh import HBM_BW, PEAK_FLOPS_BF16
+    from repro.launch.mesh import V5E, peaks
 
     spec_j = spec.replace(exec_backend="jnp")
     eplan = engine_mod.plan(spec_j, trace=trace, knobs=knobs, num_shards=1)
@@ -373,8 +373,9 @@ def _hlo_sample(spec, knobs: Knobs, trace, batch: int) -> float:
     text = jax.jit(fn).lower(*args).compile().as_text()
     h = hlo_analysis.analyze(text)
     dispatches = 1.0 if knobs.backend == "packed" else float(spec.num_tables)
+    chip = peaks(V5E)                    # the modeled chip, not the host
     return (
-        max(h["flops"] / PEAK_FLOPS_BF16, h["bytes"] / HBM_BW)
+        max(h["flops"] / chip.flops_bf16, h["bytes"] / chip.hbm_bw)
         + DISPATCH_OVERHEAD_S * dispatches
     )
 
@@ -498,11 +499,11 @@ def _with_comm_floor(model: KernelCostModel) -> KernelCostModel:
     column is zero there), so an unfitted comm coefficient falls back to the
     analytic ICI wire rate — ranking across duplication budgets stays
     meaningful."""
-    from repro.launch.mesh import ICI_BW_PER_LINK
+    from repro.launch.mesh import V5E, peaks
 
     idx = FEATURES.index("comm_bytes")
     if model.coef[idx] > 0:
         return model
     coef = list(model.coef)
-    coef[idx] = 1.0 / (2 * ICI_BW_PER_LINK)
+    coef[idx] = 1.0 / (2 * peaks(V5E).ici_bw_per_link)
     return dataclasses.replace(model, coef=tuple(coef))

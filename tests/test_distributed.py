@@ -133,7 +133,7 @@ def test_compressed_psum_close_to_exact(mesh_runner):
 import jax, jax.numpy as jnp, numpy as np
 from jax.sharding import PartitionSpec as P
 from repro.distributed.collectives import compressed_psum, ef_step
-from repro.distributed.jax_compat import shard_map
+from jax import shard_map
 from repro.launch.mesh import make_mesh
 
 mesh = make_mesh((4,), ("d",))

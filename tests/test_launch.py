@@ -75,3 +75,32 @@ def test_dryrun_list(capsys):
     lines = [l for l in out.stdout.splitlines() if l.strip()]
     assert len(lines) == 40                      # the full assigned grid
     assert sum("run" in l for l in lines) == 32
+
+
+def test_compile_cache_placement(monkeypatch, tmp_path):
+    """JAX_COMPILATION_CACHE_DIR wins and nothing else is set; otherwise the
+    cache sits at one fixed path in the checkout."""
+    from pathlib import Path
+
+    from repro.launch import compile_cache
+
+    calls = []
+    monkeypatch.setattr(compile_cache.jax.config, "update",
+                        lambda *a: calls.append(a))
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
+    assert compile_cache.enable() == str(tmp_path)
+    assert calls == []
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR")
+    path = compile_cache.enable()
+    assert calls == [("jax_compilation_cache_dir", path)]
+    assert path == compile_cache.enable()                  # stable across calls
+    assert Path(path) == Path(__file__).resolve().parents[1] / ".jax_cache"
+
+
+def test_peak_rates_keyed_by_device_kind():
+    from repro.launch.mesh import V5E, peaks
+
+    chip = peaks(V5E)
+    assert (chip.flops_bf16, chip.hbm_bw, chip.hbm_bytes) == (197e12, 819e9, 16 * 2**30)
+    with pytest.raises(KeyError, match="'cpu'"):
+        peaks("cpu")

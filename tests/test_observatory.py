@@ -279,15 +279,16 @@ def test_attribution_modeled_total_matches_cost_model_predict():
 
 
 def test_analytic_cost_model_prices_from_chip_constants():
-    from repro.launch.mesh import HBM_BW, ICI_BW_PER_LINK
+    from repro.launch.mesh import V5E, peaks
     from repro.tune.cost_model import FEATURES
     from repro.tune.tuner import DISPATCH_OVERHEAD_S
 
     m = A.analytic_cost_model()
     coef = dict(zip(FEATURES, m.coef))
     assert coef["dispatches"] == DISPATCH_OVERHEAD_S
-    assert coef["hbm_bytes"] == pytest.approx(1.0 / HBM_BW)
-    assert coef["comm_bytes"] == pytest.approx(1.0 / (2 * ICI_BW_PER_LINK))
+    chip = peaks(V5E)
+    assert coef["hbm_bytes"] == pytest.approx(1.0 / chip.hbm_bw)
+    assert coef["comm_bytes"] == pytest.approx(1.0 / (2 * chip.ici_bw_per_link))
     assert m.source == "analytic"
 
 
