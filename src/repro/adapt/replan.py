@@ -8,7 +8,8 @@ module recomputes exactly that runtime half from a live frequency sketch:
 
 * :class:`PinnedCache` — static-residency counterpart of
   :class:`repro.cache.sram_cache.PrefetchScheduler` (same duck type:
-  ``prefetch`` / ``slots_for`` / ``cache_rows`` / ``.stats``), holding the
+  ``prefetch`` / ``rank`` / ``update`` / ``slots_for`` / ``cache_rows`` /
+  ``.stats``), holding the
   *planner-predicted* hot rows resident with **no per-batch staging DMA**.
   The oracle prefetcher re-ranks from the next batch's actual indices and
   so self-heals under drift; the pinned mode is the steady-state serving
@@ -89,6 +90,7 @@ class PinnedCache:
             if r >= 0 and int(r) not in keep_set:
                 self.slot_map[r] = -1
                 self.slot_rows[s] = -1
+                self.stats.evicted_rows += 1
         stage = np.array([r for r in rows if int(r) not in keep_set], dtype=np.int32)
         free = np.flatnonzero(self.slot_rows < 0)
         for s, r in zip(free, stage):
@@ -106,6 +108,14 @@ class PinnedCache:
 
     def prefetch(self, next_idx: np.ndarray) -> int:
         """Static residency: per-batch prefetch stages nothing."""
+        return self.update(self.rank(next_idx))
+
+    def rank(self, next_idx: np.ndarray) -> np.ndarray:
+        """Static residency: no batch wins rows (see ``pin``)."""
+        return np.zeros(0, dtype=np.int64)
+
+    def update(self, want: np.ndarray) -> int:
+        """Static residency: nothing is staged per batch."""
         return 0
 
     def slots_for(self, idx: np.ndarray, *, record: bool = True) -> np.ndarray:

@@ -9,10 +9,12 @@ buffering hides the staging DMA behind the executing batch.
 TPU realization: the "SRAM" is a VMEM-resident cache block (a ``(slots, width)``
 array) plus a host-side slot map.  Per batch:
 
-1. ``prefetch(next_idx)`` (called while batch ``t`` runs) ranks the next
-   batch's rows by in-batch access count × analyzer prefetch value, keeps
-   already-resident winners (their staging cost is zero — the paper's
-   inter-batch locality), and stages the rest into evicted slots;
+1. ``prefetch(next_idx)`` (called while batch ``t`` runs) runs two phases:
+   ``rank`` orders the next batch's rows by in-batch access count × analyzer
+   prefetch value and keeps the winners; ``update`` keeps already-resident
+   winners (their staging cost is zero — the paper's inter-batch locality),
+   evicts the rest of the residents, and stages the new winners into the
+   freed slots;
 2. ``slots_for(idx)`` translates batch ``t``'s accesses through the slot map
    — hits route to the cache block, misses stream from HBM — and records
    hit-rate / staged-row statistics (the modeled traffic).
@@ -37,6 +39,7 @@ class CacheStats:
     hits: int = 0
     staged_rows: int = 0        # rows DMA'd into the cache (prefetch traffic)
     kept_rows: int = 0          # next-batch rows already resident (free)
+    evicted_rows: int = 0       # resident rows dropped from the cache
     batches: int = 0
 
     @property
@@ -83,27 +86,41 @@ class PrefetchScheduler:
     def prefetch(self, next_idx: np.ndarray) -> int:
         """Stage batch ``t+1``'s most valuable rows; returns rows DMA'd.
 
-        Runs (in hardware: overlapped) during batch ``t``.  Rows are ranked
-        by in-batch access count + analyzer tiebreak; the top ``num_slots``
-        win residency.  Winners already resident keep their slot — only the
-        difference is staged, which is what makes steady-state Zipf traffic
-        small (the hot head barely changes between batches).
+        Runs (in hardware: overlapped) during batch ``t``: ``update(rank(
+        next_idx))``.
+        """
+        return self.update(self.rank(next_idx))
+
+    def rank(self, next_idx: np.ndarray) -> np.ndarray:
+        """The rows batch ``t+1`` should find resident, best first.
+
+        Rows are ranked by in-batch access count + analyzer tiebreak; the top
+        ``num_slots`` that the batch touches win residency.
         """
         flat = np.asarray(next_idx).reshape(-1)
         counts = np.bincount(flat, minlength=self.num_rows)
         want = np.argsort(-(counts + self.value), kind="stable")[: self.num_slots]
-        want = want[counts[want] > 0]                  # never stage untouched rows
+        return want[counts[want] > 0]                  # never stage untouched rows
 
+    def update(self, want: np.ndarray) -> int:
+        """Make ``want`` (from ``rank``) the resident set; returns rows DMA'd.
+
+        Winners already resident keep their slot — only the difference is
+        staged, which is what makes steady-state Zipf traffic small (the hot
+        head barely changes between batches).
+        """
         resident = set(int(r) for r in self.slot_rows if r >= 0)
         keep = np.array([r for r in want if int(r) in resident], dtype=np.int32)
         stage = np.array([r for r in want if int(r) not in resident], dtype=np.int32)
 
         # evict non-winners, then fill free slots with the staged rows
         keep_set = set(int(r) for r in keep)
+        evicted = 0
         for s, r in enumerate(self.slot_rows):
             if r >= 0 and int(r) not in keep_set:
                 self.slot_map[r] = -1
                 self.slot_rows[s] = -1
+                evicted += 1
         free = np.flatnonzero(self.slot_rows < 0)
         for s, r in zip(free, stage):
             self.slot_rows[s] = r
@@ -111,6 +128,7 @@ class PrefetchScheduler:
 
         self.stats.staged_rows += int(stage.size)
         self.stats.kept_rows += int(keep.size)
+        self.stats.evicted_rows += evicted
         return int(stage.size)
 
     def slots_for(self, idx: np.ndarray, *, record: bool = True) -> np.ndarray:

@@ -43,6 +43,7 @@ from repro.core import embedding_bag, hashing, packed_tables
 from repro.core import sharded_embedding as SE
 from repro.engine.plan import EmbeddingPlan, plan as _plan
 from repro.engine.spec import EngineSpec
+from repro.kernels import ops
 
 
 # ---------------------------------------------------------------------------
@@ -53,17 +54,17 @@ from repro.engine.spec import EngineSpec
 @functools.partial(jax.jit, static_argnames=("plan", "interpret"))
 def _serve_gather_jit(packed, idx, slot, cache_rows, plan: EmbeddingPlan,
                       interpret: bool | None = None):
-    from repro.kernels import ops
-
     # Trace-time bump: a counter inside a jitted body counts *traces*, not
     # calls, so this is the compiled-program count for the serving dispatch.
     # The online re-planner's runtime-arg swaps must leave it at 1.
     obs.inc("engine/compile/serve_gather")
     layout = plan.layout
-    streams = packed_tables.pack_indices(idx, layout)
-    streams["slot"] = packed_tables.global_slots(slot, layout)
+    with jax.named_scope("index_pack"):
+        streams = packed_tables.pack_indices(idx, layout)
+        streams["slot"] = packed_tables.global_slots(slot, layout)
     # the cache-block gather IS the staging DMA (overlapped on hardware)
-    cache = packed[packed_tables.big_key(layout.kind)][cache_rows]
+    with jax.named_scope("cache_stage"):
+        cache = packed[packed_tables.big_key(layout.kind)][cache_rows]
     pooled = ops.packed_multi_pooled(
         {**packed, "cache": cache}, streams,
         kind=layout.kind, dims=layout.tt_dims, exec_mode=plan.spec.exec_backend,
@@ -80,6 +81,7 @@ class EmbeddingEngine:
         self.plan = plan
         self.spec = plan.spec
         self.bags = list(plan.spec.bags)
+        self._grid_steps: dict[int, int | None] = {}
         # telemetry: the active plan's summary rides along with any metrics
         # snapshot taken while this engine serves (repro.obs is a no-op when
         # disabled, so plain compiles pay nothing).
@@ -297,8 +299,6 @@ class EmbeddingEngine:
         cores already VMEM-pinned); hashed sets fall back to the plain bag.
         """
         obs.inc("engine/dispatch/cached_lookup")
-        from repro.kernels import ops
-
         bag = self.bags[table]
         emb = bag.emb
         if emb.kind == "qr":
@@ -351,6 +351,20 @@ class EmbeddingEngine:
             raise ValueError("plan is not packed; serve_gather needs a layout")
         obs.inc("engine/dispatch/serve_gather")
         return _serve_gather_jit(packed, idx, slot, cache_rows, self.plan)
+
+    def grid_steps(self, batch: int) -> int | None:
+        """Grid steps of the packed megakernel that ``serve_gather`` runs for
+        a batch of ``batch`` samples, pad bags included (``ops.packed_grid``,
+        so the count follows the kernel's own grid); None where no kernel
+        tile fits the row width."""
+        if batch not in self._grid_steps:
+            layout = self.plan.layout
+            grid = ops.packed_grid(
+                layout.kind, batch * layout.num_tables, self.bags[0].pooling,
+                layout.dim, dim_block=self.plan.dim_block,
+            )
+            self._grid_steps[batch] = None if grid is None else grid[2]
+        return self._grid_steps[batch]
 
     def packed_cache_rows(self, schedulers) -> "np.ndarray":
         """Per-table scheduler state -> the packed cache block's global rows."""

@@ -80,21 +80,33 @@ def accumulate(out_ref, row) -> None:
         out_ref[...] = out_ref[...] + row
 
 
+def bag_grid(bags: int, k_steps: int, n_streams: int, dim: int,
+             bd: int) -> tuple[int, int, int]:
+    """``(chunk, n_chunks, steps)`` of ``run_bags`` over ``bags`` bags of
+    ``k_steps`` entries in ``n_streams`` index streams: each chunk is one
+    grid ``(chunk, k_steps, dim // bd)`` sized so its streams fit
+    ``SMEM_STREAM_BYTES``; ``steps`` counts every grid step of every chunk,
+    pad bags included."""
+    per_bag = 4 * k_steps * n_streams
+    chunk = min(bags, max(8, SMEM_STREAM_BYTES // per_bag // 8 * 8))
+    n = -(-bags // chunk)
+    return chunk, n, n * chunk * k_steps * (dim // bd)
+
+
 def run_bags(body, streams, operands, in_specs, *, dim: int, bd: int,
-             interpret: bool) -> jax.Array:
+             interpret: bool, name: str | None = None) -> jax.Array:
     """Run a bag kernel over every bag: grid ``(bags, K, dim // bd)``.
 
     ``streams`` are (B, K) int index streams, scalar-prefetched flat (a body
     or index map reads bag b's k-th entry at ``b * K + k``, see ``step``);
     ``body(*stream_refs, *operand_refs, out_ref)`` accumulates bag b into
     its ``(1, bd)`` fp32 output row.  The bag axis is chunked to fit the
-    streams in SMEM; pad bags read entry 0 of every stream and are dropped.
-    Returns (B, dim) f32.
+    streams in SMEM (``bag_grid``); pad bags read entry 0 of every stream
+    and are dropped.  ``name`` names the ``pallas_call`` (the kernel's name
+    in the compiled program).  Returns (B, dim) f32.
     """
     bsz, k_steps = streams[0].shape
-    per_bag = 4 * k_steps * len(streams)
-    chunk = min(bsz, max(8, SMEM_STREAM_BYTES // per_bag // 8 * 8))
-    n = -(-bsz // chunk)
+    chunk, n, _steps = bag_grid(bsz, k_steps, len(streams), dim, bd)
     call = pl.pallas_call(
         body,
         grid_spec=pltpu.PrefetchScalarGridSpec(
@@ -105,6 +117,7 @@ def run_bags(body, streams, operands, in_specs, *, dim: int, bd: int,
         ),
         out_shape=jax.ShapeDtypeStruct((chunk, 1, dim), jnp.float32),
         interpret=interpret,
+        name=name,
     )
     flat = [
         jnp.pad(s.astype(jnp.int32), ((0, n * chunk - bsz), (0, 0)))

@@ -63,7 +63,7 @@ def _stream_spec(bd: int, k_steps: int) -> pl.BlockSpec:
     return row_spec(bd, row)
 
 
-@functools.partial(jax.jit, static_argnames=("dim_block", "interpret"))
+@functools.partial(jax.jit, static_argnames=("dim_block", "interpret", "name"))
 def cached_bag(
     table: jax.Array,
     cache: jax.Array,
@@ -72,12 +72,14 @@ def cached_bag(
     *,
     dim_block: int | None = None,
     interpret: bool = False,
+    name: str = "cached_bag",
 ) -> jax.Array:
     """Cached pooled bag: out[b] = Σ_k (slot[b,k] >= 0 ? C[slot] : T[idx]).
 
     table: (rows, dim) or its ``row_view`` in HBM; cache: (slots, dim)
     VMEM-resident (the staged block); idx/slot: (B, K) int32.  Returns
-    (B, dim) in the table dtype (fp32 accumulation inside).
+    (B, dim) in the table dtype (fp32 accumulation inside).  ``name`` names
+    the kernel in the compiled program.
     """
     k_steps = idx.shape[1]
     dim = table.shape[-1]
@@ -88,12 +90,12 @@ def cached_bag(
         functools.partial(_cached_kernel, k_steps=k_steps),
         [idx, slot], [row_view(table), cache],
         [_stream_spec(bd, k_steps), resident(cache.shape[0], bd)],
-        dim=dim, bd=bd, interpret=interpret,
+        dim=dim, bd=bd, interpret=interpret, name=name,
     )
     return out.astype(table.dtype)
 
 
-@functools.partial(jax.jit, static_argnames=("dim_block", "interpret"))
+@functools.partial(jax.jit, static_argnames=("dim_block", "interpret", "name"))
 def cached_qr_bag(
     q_table: jax.Array,
     cache: jax.Array,
@@ -104,13 +106,14 @@ def cached_qr_bag(
     *,
     dim_block: int | None = None,
     interpret: bool = False,
+    name: str = "cached_qr_bag",
 ) -> jax.Array:
     """Cached pooled QR bag:
     out[b] = Σ_k ( (slot >= 0 ? C[slot] : Q[q_idx]) + R[r_idx] ).
 
     The R LUT and the cache block are both VMEM-resident; only cache misses
     touch HBM.  q_table: (rows, dim) or its ``row_view``; q_idx/slot/r_idx:
-    (B, K) int32 -> (B, dim).
+    (B, K) int32 -> (B, dim).  ``name`` as in ``cached_bag``.
     """
     k_steps = q_idx.shape[1]
     dim = q_table.shape[-1]
@@ -126,6 +129,6 @@ def cached_qr_bag(
             resident(cache.shape[0], bd),
             resident(r_lut.shape[0], bd),
         ],
-        dim=dim, bd=bd, interpret=interpret,
+        dim=dim, bd=bd, interpret=interpret, name=name,
     )
     return out.astype(q_table.dtype)

@@ -25,6 +25,7 @@ import numpy as np
 from repro.kernels import gnr_bag as _gnr
 from repro.kernels import qr_gather as _qr
 from repro.kernels import ref
+from repro.kernels.blocks import bag_grid
 from repro.tune import knobs as _knobs
 
 
@@ -467,6 +468,31 @@ def _packed_tt_diff_bwd(dims, interpret, res, ct):
 _packed_tt_diff.defvjp(_packed_tt_diff_fwd, _packed_tt_diff_bwd)
 
 
+# The index streams of each kind's packed megakernel, in the order it takes
+# them: ``packed_multi_pooled`` passes them so, and ``packed_grid`` counts
+# the kernel's grid from their number.
+PACKED_STREAMS = {
+    "dense": ("idx", "slot"),
+    "qr": ("q_idx", "slot", "r_idx"),
+    "tt": ("i1", "i2", "i3", "slot"),
+}
+
+
+def packed_grid(kind: str, bags: int, k_steps: int, dim: int, *,
+                dim_block: int | None = None) -> tuple[int, int, int] | None:
+    """``blocks.bag_grid`` of the packed megakernel of ``kind`` over ``bags``
+    bags of ``k_steps`` entries: ``(chunk, n_chunks, steps)``, as the kernel
+    runs it; None for a dim that no lane tile fits (the jnp reference runs
+    instead).  The TT kernel takes the whole row as its tile."""
+    if kind == "tt":
+        bd = dim if dim % 8 == 0 else None
+    else:
+        bd = _resolve_dim_block(dim, dim_block, True)
+    if bd is None:
+        return None
+    return bag_grid(bags, k_steps, len(PACKED_STREAMS[kind]), dim, bd)
+
+
 def packed_multi_pooled(
     params: dict,
     streams: dict,
@@ -500,27 +526,26 @@ def packed_multi_pooled(
         "kernel": True,
         "jnp": False,
     }[exec_mode]
+    if kind not in PACKED_STREAMS:
+        raise ValueError(f"packed_multi_pooled: unsupported kind {kind!r}")
     if not use_kernel:                       # the oracles take flat rows
         params = {k: _flat(v) for k, v in params.items()}
     interp = _interpret_default() if interpret is None else bool(interpret)
+    ids = tuple(streams[k] for k in PACKED_STREAMS[kind])
     if kind == "qr":
-        args = (params["q"], params["cache"], params["r"],
-                streams["q_idx"], streams["slot"], streams["r_idx"])
+        args = (params["q"], params["cache"], params["r"], *ids)
         if use_kernel:
             return _packed_qr_diff(*args, interp, dim_block)
         return ref.packed_qr_bag_ref(*args)
     if kind == "tt":
-        args = (params["g1"], params["g2"], params["g3"], params["cache"],
-                streams["i1"], streams["i2"], streams["i3"], streams["slot"])
+        args = (params["g1"], params["g2"], params["g3"], params["cache"], *ids)
         if use_kernel:
             return _packed_tt_diff(*args, dims, interp)
         return ref.packed_tt_bag_ref(*args, dims=dims)
-    if kind == "dense":
-        args = (params["table"], params["cache"], streams["idx"], streams["slot"])
-        if use_kernel:
-            return _packed_dense_diff(*args, interp, dim_block)
-        return ref.packed_bag_ref(*args)
-    raise ValueError(f"packed_multi_pooled: unsupported kind {kind!r}")
+    args = (params["table"], params["cache"], *ids)
+    if use_kernel:
+        return _packed_dense_diff(*args, interp, dim_block)
+    return ref.packed_bag_ref(*args)
 
 
 def gnr_pooled_dense(

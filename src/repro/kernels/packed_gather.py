@@ -116,7 +116,8 @@ def packed_bag(
     """
     _check_resident(cache=cache)
     return _cg.cached_bag(
-        table, cache, idx, slot, dim_block=dim_block, interpret=interpret
+        table, cache, idx, slot, dim_block=dim_block, interpret=interpret,
+        name="packed_dense_bag",
     )
 
 
@@ -144,7 +145,7 @@ def packed_qr_bag(
     _check_resident(cache=cache, r_lut=r_lut)
     return _cg.cached_qr_bag(
         q_table, cache, r_lut, q_idx, slot, r_idx,
-        dim_block=dim_block, interpret=interpret,
+        dim_block=dim_block, interpret=interpret, name="packed_qr_bag",
     )
 
 
@@ -204,6 +205,6 @@ def packed_tt_bag(
             resident(*g1.shape),
             resident(*g3.shape),
         ],
-        dim=dim, bd=dim, interpret=interpret,
+        dim=dim, bd=dim, interpret=interpret, name="packed_tt_bag",
     )
     return out.astype(g2.dtype)

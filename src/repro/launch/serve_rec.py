@@ -23,21 +23,31 @@ timed separately (``compile_s``) and excluded from the steady-state window;
 every steady-state batch records a latency sample, so results carry
 p50/p95/p99 instead of a single wall-clock number, plus the per-batch traffic
 accounting (cache hits, modeled HBM bytes, comm bytes killed by duplication).
-``--metrics-json`` dumps the full metric registry; ``--trace-out`` writes a
-Chrome-trace/Perfetto JSON of the stage spans (pack -> h2d -> dispatch ->
-device compute -> interact) — tracing fences each stage with
-``block_until_ready`` for honest durations, which serializes the overlap
-pipeline, so never compare a traced run's QPS against an untraced one.
+``--metrics-json`` dumps the full metric registry.  Two traces, two views:
+
+* ``--trace-out`` writes the fenced host-only Chrome-trace/Perfetto JSON of
+  the stage spans (prefetch [cache_rank, cache_update] -> pack -> h2d ->
+  dispatch -> device compute -> interact): each stage is fenced with
+  ``block_until_ready`` for honest durations, which serializes the overlap
+  pipeline, so never compare a traced run's QPS against an untraced one;
+* ``--profile-dir`` writes an unfenced ``jax.profiler`` profile of the
+  steady-state batches: the same spans as host annotations (``batch`` as
+  the profiler's step marker) on one clock with the device ops, the gather
+  megakernel among them by its ``pallas_call`` name
+  (``packed_{dense,qr,tt}_bag``).  Load it in TensorBoard or Perfetto.
 
 Usage (CPU smoke):
     PYTHONPATH=src python -m repro.launch.serve_rec --arch dlrm-qr --smoke
     PYTHONPATH=src python -m repro.launch.serve_rec --arch dlrm-tt --tiny \
         --metrics-json metrics.json --trace-out trace.json
+    PYTHONPATH=src python -m repro.launch.serve_rec --arch dlrm-qr --tiny \
+        --profile-dir profile/
 """
 
 from __future__ import annotations
 
 import argparse
+import contextlib
 import dataclasses
 import functools
 import json
@@ -179,7 +189,7 @@ _percentiles = obs.latency_percentiles
 def run_pipeline(cfg, *, batch: int = 16, batches: int = 6, alpha: float = 1.05,
                  shards: int = 4, seed: int = 0, mode: str = "overlap",
                  state: ServeState | None = None, params=None,
-                 fence: bool = False) -> dict:
+                 fence: bool = False, profile_dir: str | None = None) -> dict:
     """Serve ``batches`` queued request batches; returns logits + measured QPS
     + the per-batch latency distribution + the traffic accounting.
 
@@ -197,6 +207,14 @@ def run_pipeline(cfg, *, batch: int = 16, batches: int = 6, alpha: float = 1.05,
     cycle time (the tail drain folds into the last sample).  ``fence=True``
     (set by ``--trace-out``) syncs after every stage so the trace spans carry
     device time — it serializes the overlap pipeline, perturbing QPS.
+    ``profile_dir`` runs the steady-state batches (not batch 0) under
+    ``jax.profiler.trace(profile_dir)``.
+
+    Each batch's ``prefetch`` runs the schedulers phase by phase, every
+    table's ``rank`` in a ``cache_rank`` span, then every table's
+    ``update`` in a ``cache_update`` span (the tables are independent, so
+    the slots are those of table-by-table ``prefetch``); ``dispatch`` spans
+    carry the megakernel's ``grid_steps``.
     """
     if params is None:
         params, _ = dlrm.init_dlrm(jax.random.PRNGKey(seed), cfg)
@@ -218,14 +236,23 @@ def run_pipeline(cfg, *, batch: int = 16, batches: int = 6, alpha: float = 1.05,
     ]                                          # (B, T, K) big-subtable rows
 
     gather = make_packed_gather(params, state)
+    grid_steps = state.engine.grid_steps(batch)
 
     def head(params, dense, pooled):
         return _head_jit(params, dense, pooled, cfg)
 
     def prefetch(t: int) -> None:
+        rows = rows_np[t]
         with obs.span("prefetch", batch=t):
-            for i in range(cfg.num_tables):
-                scheds[i].prefetch(rows_np[t][:, i])
+            with obs.span("cache_rank", batch=t):
+                want = [s.rank(rows[:, i]) for i, s in enumerate(scheds)]
+            with obs.span("cache_update", batch=t) as sp:
+                before = _moved(scheds) if obs.enabled() else None
+                for s, w in zip(scheds, want):
+                    s.update(w)
+                if before is not None:
+                    sp.set(**{k: v - before[k]
+                              for k, v in _moved(scheds).items()})
 
     def dispatch_gather(t: int):
         """Translate batch t through the slot maps and enqueue its megakernel."""
@@ -239,8 +266,8 @@ def run_pipeline(cfg, *, batch: int = 16, batches: int = 6, alpha: float = 1.05,
         with obs.span("h2d", batch=t):         # host-to-device index upload
             args = (jnp.asarray(idx_np[t]), jnp.asarray(slot),
                     jnp.asarray(cache_rows))
-        with obs.span("dispatch", batch=t):    # megakernel enqueue
-            pooled = gather(*args)
+        with obs.span("dispatch", batch=t, grid_steps=grid_steps):
+            pooled = gather(*args)             # megakernel enqueue
         if fence:
             with obs.span("device_compute", batch=t):
                 jax.block_until_ready(pooled)
@@ -265,48 +292,52 @@ def run_pipeline(cfg, *, batch: int = 16, batches: int = 6, alpha: float = 1.05,
     compile_s = time.perf_counter() - tc
     logits[0] = np.asarray(warm)
 
-    t0 = time.perf_counter()
-    if mode == "overlap":
-        if batches > 1:
-            prefetch(1)
-            pooled = dispatch_gather(1)
-        prev = time.perf_counter()
-        for t in range(1, batches):
-            # enqueue batch t's head, then stage + dispatch batch t+1's
-            # gather while it runs; block only at the tail of the stream
-            with obs.span("batch", batch=t, mode=mode):
-                out = interact(t, pooled)
-                if t + 1 < batches:
-                    prefetch(t + 1)
-                    pooled = dispatch_gather(t + 1)
-                logits[t] = out
-            if t < batches - 1:            # cycle time: enqueue-to-enqueue
-                now = time.perf_counter()
-                lats.append(now - prev)
-                prev = now
+    profiler = (jax.profiler.trace(profile_dir,
+                                   profiler_options=_profile_options())
+                if profile_dir else contextlib.nullcontext())
+    with profiler:
+        t0 = time.perf_counter()
+        if mode == "overlap":
+            if batches > 1:
+                prefetch(1)
+                pooled = dispatch_gather(1)
+            prev = time.perf_counter()
+            for t in range(1, batches):
+                # enqueue batch t's head, then stage + dispatch batch t+1's
+                # gather while it runs; block only at the tail of the stream
+                with obs.span("batch", batch=t, mode=mode):
+                    out = interact(t, pooled)
+                    if t + 1 < batches:
+                        prefetch(t + 1)
+                        pooled = dispatch_gather(t + 1)
+                    logits[t] = out
+                if t < batches - 1:            # cycle time: enqueue-to-enqueue
+                    now = time.perf_counter()
+                    lats.append(now - prev)
+                    prev = now
+                    obs.observe_batch(batch=t, mode=mode, latency_s=lats[-1])
+            with obs.span("tail_sync", mode=mode):
+                jax.block_until_ready(logits[-1] if batches > 1 else warm)
+            if batches > 1:                    # last cycle includes the drain
+                lats.append(time.perf_counter() - prev)
+                obs.observe_batch(batch=batches - 1, mode=mode,
+                                  latency_s=lats[-1])
+            logits = [np.asarray(x) for x in logits]
+        elif mode == "sequential":
+            for t in range(1, batches):
+                tb = time.perf_counter()
+                with obs.span("batch", batch=t, mode=mode):
+                    prefetch(t)
+                    pooled = dispatch_gather(t)
+                    out = interact(t, pooled)
+                    with obs.span("block", batch=t):
+                        jax.block_until_ready(out)     # per-batch sync: the baseline
+                lats.append(time.perf_counter() - tb)
+                logits[t] = np.asarray(out)
                 obs.observe_batch(batch=t, mode=mode, latency_s=lats[-1])
-        with obs.span("tail_sync", mode=mode):
-            jax.block_until_ready(logits[-1] if batches > 1 else warm)
-        if batches > 1:                    # last cycle includes the drain
-            lats.append(time.perf_counter() - prev)
-            obs.observe_batch(batch=batches - 1, mode=mode,
-                              latency_s=lats[-1])
-        logits = [np.asarray(x) for x in logits]
-    elif mode == "sequential":
-        for t in range(1, batches):
-            tb = time.perf_counter()
-            with obs.span("batch", batch=t, mode=mode):
-                prefetch(t)
-                pooled = dispatch_gather(t)
-                out = interact(t, pooled)
-                with obs.span("block", batch=t):
-                    jax.block_until_ready(out)     # per-batch sync: the baseline
-            lats.append(time.perf_counter() - tb)
-            logits[t] = np.asarray(out)
-            obs.observe_batch(batch=t, mode=mode, latency_s=lats[-1])
-    else:
-        raise ValueError(f"unknown mode {mode!r}")
-    wall_s = time.perf_counter() - t0
+        else:
+            raise ValueError(f"unknown mode {mode!r}")
+        wall_s = time.perf_counter() - t0
 
     for lat in lats:                       # the SLO histograms (when enabled)
         obs.observe(f"serve/{mode}/batch_latency_s", lat)
@@ -344,6 +375,22 @@ def run_pipeline(cfg, *, batch: int = 16, batches: int = 6, alpha: float = 1.05,
         "drift": state.drift.summary() if state.drift is not None else None,
         "logits": logits,
     }
+
+
+def _profile_options():
+    """The profiler without its Python tracer, which would slow the host loop
+    it records: the loop's own spans mark the host side."""
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = 0
+    return opts
+
+
+def _moved(scheds) -> dict:
+    """Rows staged, kept and evicted so far, summed over the schedulers."""
+    stats = [s.stats for s in scheds]
+    return {"staged": sum(x.staged_rows for x in stats),
+            "kept": sum(x.kept_rows for x in stats),
+            "evicted": sum(x.evicted_rows for x in stats)}
 
 
 # result keys dropped from the --json / --metrics-json records (bulk arrays
@@ -458,6 +505,10 @@ def main(argv=None) -> int:
     ap.add_argument("--trace-out", default=None, metavar="PATH",
                     help="enable telemetry; write a Chrome-trace JSON of the "
                          "stage spans (fences every stage — perturbs overlap)")
+    ap.add_argument("--profile-dir", default=None, metavar="DIR",
+                    help="enable telemetry; run the steady-state batches "
+                         "unfenced under jax.profiler.trace(DIR): host spans "
+                         "and device ops on one timeline")
     ap.add_argument("--slo", default=None, metavar="SPEC",
                     help="serving SLO, e.g. 'p99_ms=50,hit=0.5,qps=100,"
                          "objective=0.99' — enables telemetry, burn-rate "
@@ -502,10 +553,14 @@ def main(argv=None) -> int:
                          "session, e.g. 'period=8,frac=0.25' (rotations "
                          "every `period` batches)")
     args = ap.parse_args(argv)
+    if args.profile_dir and (args.trace_out or args.report or args.frontend
+                             or args.adapt):
+        ap.error("--profile-dir profiles the unfenced pipeline modes: "
+                 "drop --trace-out, --report, --frontend and --adapt")
     compile_cache.enable()
 
     telemetry = bool(args.metrics_json or args.trace_out or args.slo
-                     or args.report or args.flight_dir)
+                     or args.report or args.flight_dir or args.profile_dir)
     if telemetry:
         obs.enable()
     # --report needs device-honest stage durations for attribution, so it
@@ -629,6 +684,7 @@ def main(argv=None) -> int:
             cfg, batch=batch, batches=args.batches, alpha=args.alpha,
             shards=args.shards, seed=args.seed, mode=mode,
             state=state, params=params, fence=fence,
+            profile_dir=args.profile_dir,
         )
         tr = res["traffic"]
         ici = plan.ici_bytes_per_batch(batch, cfg.dim)
@@ -717,6 +773,9 @@ def main(argv=None) -> int:
         )
         print(f"# wrote Chrome trace to {args.trace_out} "
               f"(load in chrome://tracing or ui.perfetto.dev)")
+    if args.profile_dir:
+        print(f"# wrote the profile under {args.profile_dir} (load in "
+              f"TensorBoard or ui.perfetto.dev)")
     return 0
 
 

@@ -3,8 +3,8 @@
 One module-level switch gates everything:
 
 * ``obs.enable()`` / ``obs.disable()`` — flip telemetry for the process;
-  ``serve_rec`` enables it when ``--metrics-json`` / ``--trace-out`` is
-  passed, benchmarks leave it off.
+  ``serve_rec`` enables it when ``--metrics-json`` / ``--trace-out`` /
+  ``--profile-dir`` is passed.
 * When **disabled** (the default), every facade call is a branch on a module
   bool and an immediate return — no counters, histograms, spans, or dicts
   are allocated, so instrumented hot paths cost nothing measurable
@@ -12,7 +12,9 @@ One module-level switch gates everything:
   ``span`` returns a shared singleton).
 * When **enabled**, calls route to one process-global
   :class:`~repro.obs.metrics.MetricRegistry` and
-  :class:`~repro.obs.tracer.Tracer`.
+  :class:`~repro.obs.tracer.Tracer`; each span is also a
+  ``jax.profiler`` annotation, so a profile captured meanwhile shows it
+  beside the device ops.
 
 Instrumentation points call the facade (``obs.inc``, ``obs.observe``,
 ``obs.span``, ``obs.attach``) rather than holding metric objects, so the
@@ -55,6 +57,9 @@ class _NullSpan:
 
     def __enter__(self):
         return self
+
+    def set(self, **args) -> None:
+        pass
 
     def __exit__(self, exc_type, exc, tb):
         return False
@@ -112,6 +117,8 @@ def attach(key: str, value) -> None:
 
 
 def span(name: str, cat: str = "serve", **args):
+    """A span of ``name``: a context manager whose ``set(**args)`` adds args
+    known only at its end."""
     if _enabled:
         return _tracer.span(name, cat, args or None)
     return NULL_SPAN

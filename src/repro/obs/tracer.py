@@ -1,4 +1,5 @@
-"""Nestable span recorder emitting Chrome-trace / Perfetto JSON.
+"""Nestable span recorder emitting Chrome-trace / Perfetto JSON, and the
+same spans as annotations on the ``jax.profiler`` timeline.
 
 Records the serving pipeline's stage structure — pack -> host-to-device ->
 megakernel dispatch -> device compute -> interaction head — as *complete*
@@ -8,14 +9,24 @@ reconstructs the flame from [ts, ts+dur) containment per (pid, tid), and the
 recorder keeps a thread-local stack only so each event can also carry its
 depth in ``args`` (handy for tests and offline tools).
 
-Device work enqueued by jax is asynchronous, so a span around a dispatch call
-measures *enqueue* cost unless the caller fences; the serving driver fences
-each stage with ``jax.block_until_ready`` when tracing is requested
-(``serve_rec --trace-out``), trading pipeline overlap for honest per-stage
-durations — the Chrome trace documents a *fenced* run.
+Every span also enters a ``jax.profiler.TraceAnnotation`` of its name, its
+args as the annotation's metadata; a ``batch`` span that carries a batch
+number enters a ``jax.profiler.StepTraceAnnotation`` instead, so profile
+viewers mark step boundaries.  Outside a profiler capture an annotation
+costs a flag check; inside one, the spans sit on the host rows of the
+profile, on the profiler's clock, beside the device ops.
 
-Timestamps are microseconds from the tracer's construction (``perf_counter``
-based), matching the format's expectation of monotonic us.
+Device work enqueued by jax is asynchronous, so a span around a dispatch call
+measures *enqueue* cost unless the caller fences.  ``serve_rec`` offers
+both views: ``serve_rec --trace-out`` fences each stage with
+``jax.block_until_ready`` and writes this recorder's host-only Chrome JSON,
+trading pipeline overlap for honest per-stage durations; ``serve_rec
+--profile-dir`` runs unfenced under ``jax.profiler.trace`` and writes the
+profile, where device time is read from the device's own rows.
+
+Timestamps in the Chrome JSON are microseconds from the tracer's
+construction (``perf_counter`` based), matching the format's expectation of
+monotonic us.
 """
 
 from __future__ import annotations
@@ -25,11 +36,27 @@ import os
 import threading
 import time
 
+from jax import profiler as jax_profiler
+
+# A span of this name that carries a ``batch`` arg marks one step.
+STEP_SPAN = "batch"
+
+
+def _annotation(name: str, args: dict | None):
+    """The profiler annotation of a span: a step marker for a ``batch``
+    span with a batch number, else a ``TraceAnnotation``; ``args`` become
+    its metadata."""
+    args = args or {}
+    if name == STEP_SPAN and "batch" in args:
+        return jax_profiler.StepTraceAnnotation(
+            name, step_num=args["batch"], **args)
+    return jax_profiler.TraceAnnotation(name, **args)
+
 
 class _Span:
     """Context manager for one complete event (allocated only when enabled)."""
 
-    __slots__ = ("tracer", "name", "cat", "args", "t0")
+    __slots__ = ("tracer", "name", "cat", "args", "t0", "note")
 
     def __init__(self, tracer: "Tracer", name: str, cat: str, args):
         self.tracer = tracer
@@ -38,9 +65,17 @@ class _Span:
         self.args = args
 
     def __enter__(self) -> "_Span":
+        self.note = _annotation(self.name, self.args)
+        self.note.__enter__()
         self.t0 = time.perf_counter()
         self.tracer._stack().append(self)
         return self
+
+    def set(self, **args) -> None:
+        """Add ``args`` to the span, known only once its work has run; they
+        reach both its event and its annotation."""
+        self.args = {**(self.args or {}), **args}
+        self.note.set_metadata(**args)
 
     def __exit__(self, exc_type, exc, tb) -> bool:
         t1 = time.perf_counter()
@@ -62,6 +97,7 @@ class _Span:
             "tid": threading.get_ident() & 0x7FFFFFFF,
             "args": args,
         })
+        self.note.__exit__(exc_type, exc, tb)
         return False
 
 

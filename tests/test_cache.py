@@ -3,6 +3,8 @@ duplication planner, the plan-aware sharded GnR, and the serve_rec driver."""
 
 import numpy as np
 import pytest
+from hypothesis import given, settings
+from hypothesis import strategies as st
 
 from repro.cache import duplication, intra_gnr, sram_cache
 from repro.core import placement
@@ -118,6 +120,60 @@ def test_scheduler_value_tiebreak():
     sched = sram_cache.PrefetchScheduler(10, 1, value)
     sched.prefetch(np.array([3, 7]))             # tied counts; 7 has value
     assert sched.slot_rows[0] == 7
+
+
+def test_scheduler_counts_evictions_exactly():
+    sched = sram_cache.PrefetchScheduler(num_rows=16, num_slots=3)
+    sched.prefetch(np.array([1, 1, 2, 3]))       # cold: 3 staged, none evicted
+    assert (sched.stats.staged_rows, sched.stats.kept_rows,
+            sched.stats.evicted_rows) == (3, 0, 0)
+    want = sched.rank(np.array([3, 3, 3, 7, 7, 1]))
+    assert want.tolist() == [3, 7, 1]            # by count, then by row id
+    assert sched.update(want) == 1               # 7 staged; 2 evicted
+    assert (sched.stats.staged_rows, sched.stats.kept_rows,
+            sched.stats.evicted_rows) == (4, 2, 1)
+    assert sched.update(sched.rank(np.array([9]))) == 1   # 3, 7, 1 all go
+    assert sched.stats.evicted_rows == 4
+    assert sorted(sched.slot_rows.tolist()) == [-1, -1, 9]
+
+
+@settings(max_examples=25, deadline=None)
+@given(seed=st.integers(0, 2**31 - 1), tables=st.integers(1, 4),
+       slots=st.integers(1, 12), batches=st.integers(1, 6))
+def test_phase_major_rank_update_matches_per_table_prefetch(
+        seed, tables, slots, batches):
+    """Every table ranked, then every table updated (the serving loop's
+    order) leaves each scheduler exactly as table-by-table ``prefetch``."""
+    rng = np.random.default_rng(seed)
+    rows = 40
+    values = [rng.random(rows) if t % 2 else None for t in range(tables)]
+    ref = [sram_cache.PrefetchScheduler(rows, slots, v) for v in values]
+    got = [sram_cache.PrefetchScheduler(rows, slots, v) for v in values]
+    for _ in range(batches):
+        batch = rng.zipf(1.3, size=(6, tables, 4)) % rows
+        for t, s in enumerate(ref):
+            s.prefetch(batch[:, t])
+        want = [s.rank(batch[:, t]) for t, s in enumerate(got)]
+        for s, w in zip(got, want):
+            s.update(w)
+        for a, b in zip(ref, got):
+            assert np.array_equal(a.slot_map, b.slot_map)
+            assert np.array_equal(a.slot_rows, b.slot_rows)
+            assert a.stats == b.stats
+            a.slots_for(batch[:, 0])
+            b.slots_for(batch[:, 0])
+
+
+def test_pinned_cache_has_the_scheduler_phases():
+    from repro.adapt.replan import PinnedCache
+
+    c = PinnedCache(16, 2, rows=np.array([1, 2]))
+    assert c.rank(np.array([5, 5, 6])).size == 0
+    assert c.update(np.array([5, 6])) == 0
+    assert sorted(c.pinned_rows().tolist()) == [1, 2]
+    c.pin(np.array([2, 9]))                      # 1 evicted, 2 kept
+    assert (c.stats.staged_rows, c.stats.kept_rows,
+            c.stats.evicted_rows) == (3, 1, 1)
 
 
 # ---------------------------------------------------------------------------

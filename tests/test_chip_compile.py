@@ -158,3 +158,7 @@ def test_serve_gather_step_compiles(one_chip, arch):
     # (rows, r, d2*r) is already tiled.
     copies = 1 if layout.kind == "qr" else 0
     _table_copies_at_most(compiled, big.size * big.dtype.itemsize, copies)
+    # stable names for a profile: the megakernel's, and the two scopes
+    text = compiled.as_text()
+    assert f"%packed_{layout.kind}_bag" in text
+    assert "/cache_stage/" in text and "/index_pack/" in text

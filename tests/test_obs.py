@@ -230,6 +230,53 @@ def test_disabled_span_is_shared_singleton():
     assert s1 is s2 is obs.NULL_SPAN
 
 
+def _profiled(tmp_path, body):
+    """Host-plane events of a ``jax.profiler`` capture around ``body()``, as
+    ``{name: [stats dict, ...]}``."""
+    import glob
+
+    import jax
+    from jax.profiler import ProfileData
+
+    with jax.profiler.trace(str(tmp_path)):
+        body()
+    path, = glob.glob(f"{tmp_path}/plugins/profile/*/*.xplane.pb")
+    out: dict = {}
+    for plane in ProfileData.from_file(path).planes:
+        if plane.name.startswith("/host:"):
+            for line in plane.lines:
+                for e in line.events:
+                    out.setdefault(e.name, []).append(dict(e.stats))
+    return out
+
+
+def test_enabled_span_is_on_the_profilers_host_plane(tmp_path):
+    obs.enable()
+
+    def body():
+        with obs.span("x", batch=3):
+            with obs.span("batch", batch=4, mode="sequential") as sp:
+                sp.set(staged=7)
+
+    events = _profiled(tmp_path, body)
+    assert events["x"] == [{"batch": 3}]
+    step, = events["batch"]
+    assert step["step_num"] == 4 and step["staged"] == 7
+    assert step["mode"] == "sequential"
+    got = {e["name"]: e["args"] for e in obs.tracer().events}
+    assert got["batch"]["staged"] == 7 and got["x"]["batch"] == 3
+
+
+def test_disabled_span_reaches_no_profile(tmp_path):
+    def body():
+        with obs.span("x", batch=3) as sp:
+            assert sp is obs.NULL_SPAN
+            sp.set(staged=1)
+
+    assert "x" not in _profiled(tmp_path, body)
+    assert obs.tracer().events == []
+
+
 def test_enable_records_and_reset_wipes():
     obs.enable()
     obs.inc("x", 2)
