@@ -362,6 +362,7 @@ class EmbeddingEngine:
             grid = ops.packed_grid(
                 layout.kind, batch * layout.num_tables, self.bags[0].pooling,
                 layout.dim, dim_block=self.plan.dim_block,
+                dtype=self.bags[0].emb.param_dtype,
             )
             self._grid_steps[batch] = None if grid is None else grid[2]
         return self._grid_steps[batch]

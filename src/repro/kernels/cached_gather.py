@@ -8,17 +8,22 @@ SRAM while the remaining rows stream from DRAM.  TPU realization:
   BlockSpec index map, resident across all grid steps);
 * the **slot map** rides in SMEM via scalar prefetch alongside the indices:
   for each bag element the kernel reads ``slot[b, k]`` and routes the access
-  — ``slot >= 0`` selects the VMEM cache row, ``slot < 0`` selects the row
-  DMA'd from HBM by the streamed operand;
-* the **streamed operand**'s index map sends misses to ``idx[b, k]`` and pins
-  hits to block 0: Pallas elides the DMA when consecutive grid steps name the
-  same block, so runs of cache hits issue *no* HBM traffic — the kernel-level
-  analogue of the cache absorbing DRAM accesses;
-* accumulation is fp32 in a VMEM output block revisited across the K steps
-  (bank-group MAC + register file), exactly like ``gnr_bag``.
+  — ``slot >= 0`` selects the VMEM cache row, ``slot < 0`` the table row in
+  HBM;
+* **32-bit tables** run a block of bags per grid step
+  (``blocks.run_bag_blocks``): each miss is one row copy the kernel starts
+  itself, all of a block's copies in flight at once, and a hit copies
+  nothing from HBM — the kernel-level analogue of the cache absorbing DRAM
+  accesses;
+* **16-bit tables** (bf16 training) stream one row per grid step
+  (``blocks.run_bags``), since Mosaic refuses a one-row copy into a packed
+  16-bit VMEM buffer: the streamed operand's index map sends misses to
+  ``idx[b, k]`` and pins hits to block 0, and Pallas elides the DMA when
+  consecutive grid steps name the same block;
+* accumulation is fp32 (bank-group MAC + register file), exactly like
+  ``gnr_bag``.
 
-Block layout: see ``repro.kernels.blocks`` (streamed rows and outputs are
-``(rows, 1, dim)`` views; the cache block and R LUT stay 2-D f32).
+Block layout: see ``repro.kernels.blocks``.
 """
 
 from __future__ import annotations
@@ -30,10 +35,17 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
 from repro.kernels.blocks import (
-    accumulate, f32_rows, resident, row_spec, row_view, run_bags, step,
+    accumulate, f32_rows, resident, row_spec, row_view, run_bag_blocks,
+    run_bags, step,
 )
 
 DEFAULT_DIM_BLOCK = 512
+
+
+def bag_blocks(dtype) -> bool:
+    """Whether tables of ``dtype`` run ``run_bag_blocks`` (32-bit rows) or
+    stream a row per step through ``run_bags`` (narrower rows)."""
+    return jnp.dtype(dtype).itemsize == 4
 
 
 def _cached_row(pos, slot_ref, row_ref, cache_ref):
@@ -85,6 +97,10 @@ def cached_bag(
     dim = table.shape[-1]
     bd = dim_block or min(dim, DEFAULT_DIM_BLOCK)
     assert dim % bd == 0, f"dim {dim} not divisible by dim_block {bd}"
+    if bag_blocks(table.dtype):
+        out = run_bag_blocks([idx, slot], table, cache, dim=dim, bd=bd,
+                             interpret=interpret, name=name)
+        return out.astype(table.dtype)
     cache = f32_rows(cache, dim)
     out = run_bags(
         functools.partial(_cached_kernel, k_steps=k_steps),
@@ -120,6 +136,10 @@ def cached_qr_bag(
     bd = dim_block or min(dim, DEFAULT_DIM_BLOCK)
     assert dim % bd == 0, f"dim {dim} not divisible by dim_block {bd}"
     assert cache.shape[-1] == dim and r_lut.shape[-1] == dim
+    if bag_blocks(q_table.dtype):
+        out = run_bag_blocks([q_idx, slot, r_idx], q_table, cache, [r_lut],
+                             dim=dim, bd=bd, interpret=interpret, name=name)
+        return out.astype(q_table.dtype)
     cache, r_lut = f32_rows(cache, dim), f32_rows(r_lut, dim)
     out = run_bags(
         functools.partial(_cached_qr_kernel, k_steps=k_steps),

@@ -22,10 +22,11 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.kernels import cached_gather as _cg
 from repro.kernels import gnr_bag as _gnr
 from repro.kernels import qr_gather as _qr
 from repro.kernels import ref
-from repro.kernels.blocks import bag_grid
+from repro.kernels.blocks import bag_grid, block_grid
 from repro.tune import knobs as _knobs
 
 
@@ -237,10 +238,8 @@ def cached_pooled(
     """Cached pooled bag for index shape (..., K) -> (..., D).
 
     ``cache`` is the prefetch scheduler's staged block; ``slot`` its per-access
-    routing (-1 = miss -> streamed HBM row).
+    routing (-1 = miss -> the HBM row).
     """
-    from repro.kernels import cached_gather as _cg
-
     interpret = _interpret_default() if interpret is None else interpret
     dim = table.shape[-1]
     bd = _resolve_dim_block(dim, dim_block, interpret)
@@ -266,8 +265,6 @@ def cached_qr_pooled(
     dim_block: int | None = None,
 ) -> jax.Array:
     """Cached pooled QR bag for index shape (..., K) -> (..., D)."""
-    from repro.kernels import cached_gather as _cg
-
     interpret = _interpret_default() if interpret is None else interpret
     dim = q_table.shape[-1]
     bd = _resolve_dim_block(dim, dim_block, interpret)
@@ -479,18 +476,22 @@ PACKED_STREAMS = {
 
 
 def packed_grid(kind: str, bags: int, k_steps: int, dim: int, *,
-                dim_block: int | None = None) -> tuple[int, int, int] | None:
-    """``blocks.bag_grid`` of the packed megakernel of ``kind`` over ``bags``
-    bags of ``k_steps`` entries: ``(chunk, n_chunks, steps)``, as the kernel
-    runs it; None for a dim that no lane tile fits (the jnp reference runs
-    instead).  The TT kernel takes the whole row as its tile."""
+                dim_block: int | None = None,
+                dtype=jnp.float32) -> tuple[int, int, int] | None:
+    """The grid of the packed megakernel of ``kind`` over ``bags`` bags of
+    ``k_steps`` entries, rows of ``dtype``: ``(chunk, n_chunks, steps)``, as
+    the kernel runs it — ``blocks.block_grid`` for dense and QR rows of 32
+    bits, ``blocks.bag_grid`` otherwise; None for a dim that no lane tile
+    fits (the jnp reference runs instead).  The TT kernel takes the whole
+    row as its tile."""
     if kind == "tt":
         bd = dim if dim % 8 == 0 else None
     else:
         bd = _resolve_dim_block(dim, dim_block, True)
     if bd is None:
         return None
-    return bag_grid(bags, k_steps, len(PACKED_STREAMS[kind]), dim, bd)
+    grid = block_grid if kind != "tt" and _cg.bag_blocks(dtype) else bag_grid
+    return grid(bags, k_steps, len(PACKED_STREAMS[kind]), dim, bd)
 
 
 def packed_multi_pooled(

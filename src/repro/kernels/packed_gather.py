@@ -10,18 +10,21 @@ is a single kernel over a **packed** layout:
   are concatenated row-major into ONE buffer; per-table row offsets turn the
   logical (table_id, row) pair into a flat packed row id **before** the kernel
   — the index streams arriving here are already global;
-* bags from every table ride one flattened stream: grid step ``g`` is bag
+* bags from every table ride one flattened stream: bag ``g`` is
   ``(sample b, table t) = divmod(g, T)``; the kernel never sees table
   boundaries, so HBM row DMAs pipeline *across* tables instead of draining
   per-table loops back-to-back;
 * the small shared subtables of every table (QR R LUTs, TT outer cores) are
   packed the same way and mapped into VMEM once — one resident block serves
   all tables;
-* cache-slot routing (PR 3's prefetch scheduler) is folded in: ``slot >= 0``
+* cache-slot routing (the prefetch scheduler) is folded in: ``slot >= 0``
   reads the packed VMEM cache block (per-table slot ranges concatenated),
-  ``slot < 0`` streams the HBM row.  Hits pin the streamed operand to block 0
-  so Pallas elides their DMAs — runs of hits issue no HBM traffic;
-* accumulation is fp32 in a VMEM output block revisited across the K steps.
+  ``slot < 0`` fetches the HBM row, so hits issue no HBM traffic;
+* dense and QR rows of 32 bits run a block of bags per grid step, the
+  kernel starting every missed row's copy itself
+  (``blocks.run_bag_blocks``); TT (and 16-bit rows) stream one row per
+  grid step (``blocks.run_bags``), accumulating in fp32 in a VMEM output
+  block revisited across the K steps.
 
 The mesh path calls the same kernels with a 1-row dummy cache and an all-miss
 slot map: masking (non-owned rows, off-shard R positions, ragged bag tails)
@@ -110,7 +113,7 @@ def packed_bag(
 
     The kernel body IS ``cached_gather.cached_bag``: the multi-table fusion
     lives entirely in the pre-offset index stream and the packed buffers, so
-    the slot-routing/hit-pinning logic stays single-sourced.  This wrapper
+    the slot routing stays single-sourced.  This wrapper
     adds the packed-layout VMEM-residency guard (the cache block here holds
     EVERY table's slots).  Returns (G, dim) in the table dtype.
     """

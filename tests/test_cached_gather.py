@@ -1,5 +1,8 @@
 """Cached-gather Pallas kernel vs the jnp oracle (interpret=True on CPU)
-across a size/skew sweep, plus integration with the serving lookup path."""
+across a size/skew sweep, plus integration with the serving lookup path.
+
+f32 tables run a block of bags per grid step (``blocks.run_bag_blocks``),
+bf16 tables a row per step (``blocks.run_bags``); the sweeps cover both."""
 
 import jax
 import jax.numpy as jnp
@@ -12,7 +15,7 @@ from repro.core.embedding_bag import BagConfig
 from repro.core.qr_embedding import EmbeddingConfig
 from repro.data.synthetic import zipf_trace
 from repro.engine import EngineSpec
-from repro.kernels import ops, ref
+from repro.kernels import cached_gather, ops, ref
 
 
 def _setup(rows, slots, dim, bk, dtype=jnp.float32, seed=0, hit_p=0.5):
@@ -30,7 +33,7 @@ def _setup(rows, slots, dim, bk, dtype=jnp.float32, seed=0, hit_p=0.5):
     return table, cache, idx, slot
 
 
-@pytest.mark.parametrize("dim", [8, 32, 128, 256])
+@pytest.mark.parametrize("dim", [8, 32, 128, 256, 64])
 @pytest.mark.parametrize("hit_p", [0.0, 0.5, 1.0])
 def test_cached_bag_size_hit_sweep(dim, hit_p):
     table, cache, idx, slot = _setup(64, 8, dim, (5, 7), hit_p=hit_p)
@@ -40,13 +43,23 @@ def test_cached_bag_size_hit_sweep(dim, hit_p):
                                rtol=1e-5, atol=1e-5)
 
 
+# (bags, K) at dim 32, or (bags, K, dim, dim_block).  With f32 rows:
+# (21, 3) is one block with 3 pad bags; (2000, 33) two chunks of 23 blocks
+# of 56 bags, pads in the last block of the last chunk; the last two split
+# the row into lane tiles, each tile its own chain of blocks.
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
-@pytest.mark.parametrize("bk", [(1, 1), (3, 16), (8, 4)])
+@pytest.mark.parametrize("bk", [(1, 1), (3, 16), (8, 4), (21, 3), (2000, 33),
+                                (70, 1, 64, 16), (9, 33, 256, 128)])
 def test_cached_qr_bag_sweep(dtype, bk):
-    table, cache, idx, slot = _setup(96, 16, 32, bk, dtype=dtype)
-    r_lut = jax.random.normal(jax.random.PRNGKey(9), (8, 32), dtype)
-    r_idx = jax.random.randint(jax.random.PRNGKey(10), bk, 0, 8)
-    out = ops.cached_qr_pooled(table, cache, r_lut, idx, slot, r_idx)
+    (b, k), (dim, bd) = bk[:2], bk[2:] or (32, None)
+    table, cache, idx, slot = _setup(96, 16, dim, (b, k), dtype=dtype)
+    r_lut = jax.random.normal(jax.random.PRNGKey(9), (8, dim), dtype)
+    r_idx = jax.random.randint(jax.random.PRNGKey(10), (b, k), 0, 8)
+    if bd is None:
+        out = ops.cached_qr_pooled(table, cache, r_lut, idx, slot, r_idx)
+    else:       # a lane tile that ``ops`` would not pick for this dim
+        out = cached_gather.cached_qr_bag(table, cache, r_lut, idx, slot, r_idx,
+                                          dim_block=bd, interpret=True)
     expect = ref.cached_qr_bag_ref(table, cache, r_lut, idx, slot, r_idx)
     np.testing.assert_allclose(
         np.asarray(out, np.float32), np.asarray(expect, np.float32),

@@ -37,6 +37,7 @@ TT_ROWS = 36_037           # 26 middle cores (1386 rows) + zero row
 TT_OUTER = 26 * 38         # 26 packed outer cores (v1 = v3 = 38)
 TT_SLOTS = 1_024           # 8 MiB of 8 KiB middle-core rows
 BAGS = 416                 # a (416, 32) bag stream: 16 samples x 26 tables
+SERVE_BAGS = 2048 * 26     # a serving batch of 2048 samples: 53,248 bags
 
 
 @pytest.fixture(scope="module")
@@ -89,6 +90,28 @@ def test_packed_row_bag_compiles(one_chip, kind, dtype):
         compiled = _compile(fn, table, cache, r_lut, stream, stream, stream)
     if dtype == jnp.float32:
         _table_copies_at_most(compiled, Q_ROWS * DIM * 4, 0)
+
+
+@pytest.mark.parametrize("kind", ["dense", "qr"])
+def test_packed_bag_blocks_compile_at_a_serving_batch(one_chip, kind):
+    """f32 dense and QR rows run a block of bags per grid step, the table
+    left in HBM: at a whole serving batch the kernel compiles, reads the
+    table in place, and its resident cache, R LUT and row buffers fit the
+    VMEM a kernel gets by default (no raised limit)."""
+    table = _sds(one_chip, (Q_ROWS, 1, DIM))
+    cache = _sds(one_chip, (SLOTS, DIM))
+    stream = _sds(one_chip, (SERVE_BAGS, K), jnp.int32)
+    if kind == "dense":
+        fn = functools.partial(packed_gather.packed_bag, interpret=False)
+        compiled = _compile(fn, table, cache, stream, stream)
+    else:
+        fn = functools.partial(packed_gather.packed_qr_bag, interpret=False)
+        r_lut = _sds(one_chip, (R_ROWS, DIM))
+        compiled = _compile(fn, table, cache, r_lut, stream, stream, stream)
+    _table_copies_at_most(compiled, Q_ROWS * DIM * 4, 0)
+    kernel, = [line for line in compiled.as_text().splitlines()
+               if f"%packed_{kind}_bag" in line and "tpu_custom_call" in line]
+    assert "scoped_memory_configs" not in kernel      # a raised VMEM limit
 
 
 def test_packed_tt_bag_compiles(one_chip):

@@ -27,6 +27,8 @@ def _bags(kind, num_tables=3, vocab=1024, dim=32, pooling=8, **kw):
 
 
 KINDS = [("dense", {}), ("qr", {"collision": 8}), ("tt", {"tt_rank": 4})]
+# dense and QR at other row widths: the bag-block runner's lane tiles
+WIDE = [("qr", {"collision": 8, "dim": 256}), ("dense", {"dim": 64})]
 
 
 # ---------------------------------------------------------------------------
@@ -71,7 +73,7 @@ def test_packable_rejects_non_uniform_and_unsupported():
 # oracle parity (packed path vs the per-table loop, both exec modes)
 # ---------------------------------------------------------------------------
 
-@pytest.mark.parametrize("kind,kw", KINDS)
+@pytest.mark.parametrize("kind,kw", KINDS + WIDE)
 @pytest.mark.parametrize("exec_mode", ["jnp", "kernel"])
 def test_packed_multi_bag_parity(kind, kw, exec_mode):
     bags = _bags(kind, **kw)
@@ -99,7 +101,7 @@ def test_packed_single_table_degenerate(kind, kw):
                                rtol=1e-5, atol=1e-5)
 
 
-@pytest.mark.parametrize("kind,kw", KINDS)
+@pytest.mark.parametrize("kind,kw", KINDS + WIDE)
 def test_packed_ragged_and_empty_bags(kind, kw):
     """Positions past a bag's length route to the zero row: a masked-oracle
     match, and an empty bag pools to exactly zero."""
@@ -197,7 +199,7 @@ def test_packed_cache_routing_matches_uncached(kind, kw):
 # gradients through the reference-recompute vjp
 # ---------------------------------------------------------------------------
 
-@pytest.mark.parametrize("kind,kw", KINDS)
+@pytest.mark.parametrize("kind,kw", KINDS + WIDE)
 def test_packed_kernel_grads_match_oracle(kind, kw):
     """The megakernel path must be training-safe: grads w.r.t. every table
     leaf equal the pure-jnp packed oracle's."""

@@ -1,6 +1,7 @@
 """What the serving loop tells a trace: the gather megakernel's grid steps
-(``blocks.bag_grid``, pinned by hand at the benchmark cells' shapes) and the
-spans ``run_pipeline`` emits around the prefetch schedulers' two phases."""
+(``blocks.block_grid`` for dense and QR, ``blocks.bag_grid`` for TT, pinned
+by hand at the benchmark cells' shapes) and the spans ``run_pipeline`` emits
+around the prefetch schedulers' two phases."""
 
 import collections
 import dataclasses
@@ -11,7 +12,7 @@ from repro import engine as engine_mod
 from repro import obs
 from repro.configs import registry
 from repro.kernels import ops
-from repro.kernels.blocks import bag_grid
+from repro.kernels.blocks import bag_grid, block_grid
 
 
 @pytest.fixture(autouse=True)
@@ -40,9 +41,29 @@ def test_bag_grid_small_batches_and_lane_tiles():
     assert bag_grid(5, 4, 2, 64, 16) == (5, 1, 80)       # 4 lane tiles a row
 
 
+# Bag blocks of f32 rows at K 32, dim 128: G = 2 MiB / (2 x 32 x 128 x 4 B)
+# = 64 bags a step.  QR: 3 streams fit 1,365 bags a chunk, 21 whole blocks =
+# 1,344; 53,248 bags -> 40 chunks x 21 = 840 steps, 6,656 -> 5 x 21 = 105.
+# Dense: 2 streams fit 2,048 bags = 32 blocks; 26 chunks exactly -> 832.
+@pytest.mark.parametrize("streams,batch,chunk,n_chunks,steps", [
+    (3, 2048, 1344, 40, 840),
+    (3, 256, 1344, 5, 105),
+    (2, 2048, 2048, 26, 832),
+])
+def test_block_grid_by_hand(streams, batch, chunk, n_chunks, steps):
+    assert block_grid(batch * 26, 32, streams, 128, 128) == (chunk, n_chunks, steps)
+
+
+def test_block_grid_small_batches_and_lane_tiles():
+    assert block_grid(5, 4, 2, 64, 64) == (8, 1, 1)      # one block, 3 pads
+    assert block_grid(5, 4, 2, 64, 16) == (8, 1, 4)      # 4 lane tiles a row
+    # K 33, dim 256: G = 2 MiB / (2 x 33 x 256 x 4 B) = 31 -> 24 bags
+    assert block_grid(50, 33, 3, 256, 256) == (72, 1, 3)
+
+
 @pytest.mark.parametrize("arch,batch,steps", [
-    ("dlrm-qr", 2048, 1_740_800),
-    ("dlrm-qr", 256, 217_600),
+    ("dlrm-qr", 2048, 840),
+    ("dlrm-qr", 256, 105),
     ("dlrm-tt", 2048, 1_703_936),
 ])
 def test_engine_grid_steps_at_published_widths(arch, batch, steps):
