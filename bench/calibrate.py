@@ -9,7 +9,8 @@ the float32 reference (a sound run: the lower readings), and on the first
 ``--control-seeds`` seeds for the two controls put in the program's place
 (the upper readings): ``control`` (tables and pooled in bfloat16, matmul
 inputs in float8) for the logits, ``bf16_tables`` (only the tables in
-bfloat16) for the pooled embeddings.  A limit goes between the two.
+bfloat16) for the pooled embeddings.  A limit goes between the two.  A
+cell on several chips is served and checked on them, as a run does.
 """
 
 from __future__ import annotations
@@ -43,6 +44,34 @@ def control_checks(s, m, limits, precision: str) -> dict:
     return harness.compare(s.params, s.window, logits, pooled, m, limits)[0]
 
 
+def readings(c, seeds, seconds: float, control_seeds: int):
+    """One record a seed for the prepared cell ``c``, on its own chips and
+    with its weights placed as a run places them."""
+    from bench import harness
+
+    for i, seed in enumerate(seeds):
+        t0 = time.time()
+        s = harness.serve(c, seed, seconds, False, process_start=t0)
+        served = time.time()
+        program, _ = harness.compare(s.params, s.window, s.logits, s.pooled,
+                                     c.model, c.limits)
+        rec = {"workload": c.name, "seed": seed,
+               **{f"program.{k}": v["value"] for k, v in program.items()}}
+        if i < control_seeds:
+            for precision in CONTROLS:
+                got = control_checks(s, c.model, c.limits, precision)
+                rec.update({f"{precision}.{k}": v["value"]
+                            for k, v in got.items()})
+        rec.update({"batches": len(s.window), "wall_s": s.result["wall_s"],
+                    "setup_s": s.setup_s, "serve_s": served - t0,
+                    "check_s": time.time() - served,
+                    "hit_rate": s.result["hit_rate"],
+                    "chips": len(c.devices), "peak_bytes": s.peak_bytes})
+        del s              # free this seed's weights before the next set-up
+        gc.collect()
+        yield rec
+
+
 def main(argv=None) -> int:
     ap = argparse.ArgumentParser(description=__doc__.splitlines()[0])
     ap.add_argument("--workload", required=True)
@@ -63,26 +92,7 @@ def main(argv=None) -> int:
     c = harness.prepare(args.workload)
     seeds = [int(x) for x in args.seeds.split(",")]
     out = open(args.out, "a") if args.out else None
-    for i, seed in enumerate(seeds):
-        t0 = time.time()
-        s = harness.serve(c, seed, args.seconds, False, process_start=t0)
-        served = time.time()
-        program, _ = harness.compare(s.params, s.window, s.logits, s.pooled,
-                                     c.model, c.limits)
-        rec = {"workload": args.workload, "seed": seed,
-               **{f"program.{k}": v["value"] for k, v in program.items()}}
-        if i < args.control_seeds:
-            for precision in CONTROLS:
-                got = control_checks(s, c.model, c.limits, precision)
-                rec.update({f"{precision}.{k}": v["value"]
-                            for k, v in got.items()})
-        rec.update({"batches": len(s.window), "wall_s": s.result["wall_s"],
-                    "setup_s": s.setup_s, "serve_s": served - t0,
-                    "check_s": time.time() - served,
-                    "hit_rate": s.result["hit_rate"],
-                    "peak_bytes": s.peak_bytes})
-        del s              # free this seed's weights before the next set-up
-        gc.collect()
+    for rec in readings(c, seeds, args.seconds, args.control_seeds):
         print(json.dumps(rec), flush=True)
         if out:
             out.write(json.dumps(rec) + "\n")

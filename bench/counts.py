@@ -31,18 +31,21 @@ def gather_counts(idx: np.ndarray, m) -> dict:
 
     Bytes: the distinct rows of every subtable the batch touches, the ids
     read and the pooled (B, T, dim) result written.  FLOPs: each distinct id
-    rebuilt once and the pooling adds.
+    rebuilt once and the pooling adds; a dense row is read, not rebuilt.
     """
     b, t, k = idx.shape
     ids = _per_table(idx, m.vocab_per_table)
     pool_flops = b * t * (k - 1) * m.dim
     io_bytes = idx.size * IDX + b * t * m.dim * F32
-    if m.kind == "qr":
+    if m.kind == "dense":
+        row_bytes = _distinct(ids) * m.dim * F32
+        flops = pool_flops
+    elif m.kind == "qr":
         q = _per_table(idx // m.collision, m.q_rows)
         r = _per_table(idx % m.collision, m.collision)
         row_bytes = (_distinct(q) + _distinct(r)) * m.dim * F32
         flops = _distinct(ids) * m.dim + pool_flops
-    else:
+    elif m.kind == "tt":
         (_, v2, v3), (d1, d2, d3), r = m.vocab_factors, m.dim_factors, m.rank
         i3 = idx % v3
         i2 = (idx // v3) % v2
@@ -60,6 +63,8 @@ def gather_counts(idx: np.ndarray, m) -> dict:
         left = n12 * 2 * d1 * r * d2 * r + nid * 2 * d1 * d2 * r * d3
         right = n23 * 2 * r * d2 * r * d3 + nid * 2 * d1 * r * d2 * d3
         flops = min(left, right) + pool_flops
+    else:
+        raise ValueError(f"no counts for embedding kind {m.kind!r}")
     return {"flops": float(flops), "bytes": float(row_bytes + io_bytes),
             "pooled_bytes": float(b * t * m.dim * F32)}
 

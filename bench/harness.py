@@ -8,7 +8,8 @@ harness finds all of them by name, so a new cell, mix or metric is a new
 file.
 
 A run:
-1. set-up: the weights from the seed (one jitted call), the program's
+1. set-up: the weights from the seed (one jitted call, placed on the cell's
+   chips where the configuration says how), the program's
    offline plan (``serve_rec.build_serve_state``), and a short
    ``run_pipeline`` call that compiles every shape and times a batch;
 2. the window: one ``run_pipeline`` call with as many batches as fill
@@ -149,7 +150,8 @@ class Context:
     setup_s: float
     spans: list[list]       # obs spans on the trace clock (trace runs)
     trace: dict | None      # trace_mod.summarize (trace runs on a TPU)
-    peaks: dict | None
+    peaks: dict | None      # one chip's published peaks
+    chips: int = 1          # the chips the cell uses
 
     @property
     def window_batches(self) -> int:
@@ -187,15 +189,16 @@ class Context:
 
     def least_s(self, part: str) -> float | None:
         """Summed roofline least time of the window's batches for ``part``
-        (``gather`` or ``step``), from ``counts``; None off a chip with
-        published peaks."""
+        (``gather`` or ``step``), from ``counts``, at the summed peaks of the
+        cell's chips; None off a chip with published peaks."""
         if self.peaks is None:
             return None
         total = 0.0
         for ids, g in zip(self.window_ids, self._gather_counts):
             c = g if part == "gather" else counts.step_counts(
                 g, counts.head_counts(ids.shape[0], self.model))
-            total += peaks_mod.least_time_s(c["flops"], c["bytes"], self.peaks)[0]
+            total += peaks_mod.least_time_s(c["flops"], c["bytes"], self.peaks,
+                                            self.chips)[0]
         return total
 
 
@@ -210,7 +213,7 @@ class Cell:
     mix: dict
     limits: dict
     cfg: object
-    devices: list
+    devices: list           # the cell's chips: the first ``chips`` JAX finds
 
 
 def prepare(workload: str, *, spec: dict | None = None, dirs: Dirs = Dirs(),
@@ -223,10 +226,11 @@ def prepare(workload: str, *, spec: dict | None = None, dirs: Dirs = Dirs(),
     mix = generator.load(cell["traffic"], dirs.traffic)
     limits = json.loads((dirs.limits / f"{workload}.json").read_text())
     devices = jax.devices()
-    if require_tpu and (devices[0].platform != "tpu"
-                        or len(devices) < cell["chips"]):
+    if ((require_tpu and devices[0].platform != "tpu")
+            or len(devices) < cell["chips"]):
         raise NoChip(f"cell {workload} needs {cell['chips']} TPU chip(s); "
                      f"JAX finds {len(devices)} {devices[0].platform} device(s)")
+    devices = devices[:cell["chips"]]
 
     from repro.configs import registry
 
@@ -273,7 +277,8 @@ def serve(c: Cell, seed: int, seconds: float, trace: bool, *,
     program_batch = synthetic.dlrm_batch
     synthetic.dlrm_batch = seam
     try:
-        params = jax.block_until_ready(model_mod.make_params(c.model, seed))
+        params = jax.block_until_ready(
+            model_mod.make_params(c.model, seed, c.devices))
         state = serve_rec.build_serve_state(c.cfg, shards=c.model.plan_shards,
                                             alpha=mix["alpha"], seed=seed)
         kw = dict(batch=mix["batch"], alpha=mix["alpha"], seed=seed,
@@ -310,7 +315,7 @@ def serve(c: Cell, seed: int, seconds: float, trace: bool, *,
             spans = trace_mod.spans_on_trace_clock(
                 obs.tracer().events[first_event:], obs.tracer().origin, sync_pc,
                 trace_mod.sync_ns(tr))
-            summary = trace_mod.summarize(tr, spans)
+            summary = trace_mod.summarize(tr, spans, len(c.devices))
         compiles = counter.value - compiles0
     finally:
         synthetic.dlrm_batch = program_batch
@@ -376,7 +381,7 @@ def run_cell(c: Cell, seed: int, seconds: float, trace: bool, *,
     ctx = Context(model=c.model, result=res, window=s.window,
                   setup_s=s.setup_s, spans=s.spans, trace=s.trace,
                   peaks=peaks_mod.peaks(dev.device_kind)
-                  if dev.platform == "tpu" else None)
+                  if dev.platform == "tpu" else None, chips=len(c.devices))
     metrics = {}
     for metric in cell_metrics(c.spec, workload, trace):
         value = reader(reader_path(c.dirs.metrics, metric["name"]))(ctx)

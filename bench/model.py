@@ -6,6 +6,12 @@ plain reference are both handed the same arrays, and the reference reads
 nothing the program made.  The tree is the one ``repro.models.dlrm.init_dlrm``
 returns (``tests/test_bench_counts.py`` pins it); its scales follow the usual
 fan-in rule so that activations stay O(1).
+
+A cell on more than one chip has its weights made in place on its chips:
+every table's rows split over a mesh axis ``model`` (the axis the program's
+sharding rules give ``vocab``), the MLPs replicated.
+``jax_threefry_partitionable`` makes the values those of the unsharded
+weights (``tests/test_bench_placement.py`` pins it).
 """
 
 from __future__ import annotations
@@ -17,12 +23,16 @@ from pathlib import Path
 
 import jax
 import jax.numpy as jnp
+import numpy as np
+from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 DIR = Path(__file__).resolve().parent / "configs"
 
-# The program stores the big subtable (QR quotient table, TT middle core) with
-# its rows padded to a multiple of this, so a mesh axis divides them.
+# The program stores the big subtable (dense table, QR quotient table, TT
+# middle core) with its rows padded to a multiple of this, so a mesh axis
+# divides them.
 ROW_PAD = 128
+ROW_AXIS = "model"
 
 
 def _pad_rows(rows: int) -> int:
@@ -42,7 +52,7 @@ class Model:
     num_dense: int
     bottom_mlp: tuple
     top_mlp: tuple
-    kind: str                    # "qr" | "tt"
+    kind: str                    # "dense" | "qr" | "tt"
     collision: int = 0           # QR
     rank: int = 0                # TT
     vocab_factors: tuple = ()    # TT (v1, v2, v3)
@@ -64,6 +74,8 @@ class Model:
 
     def table_shapes(self) -> dict:
         """Leaf name -> (rows, width) of one table, as the program stores it."""
+        if self.kind == "dense":
+            return {"table": (_pad_rows(self.vocab_per_table), self.dim)}
         if self.kind == "qr":
             return {"q": (_pad_rows(self.q_rows), self.dim),
                     "r": (self.collision, self.dim)}
@@ -93,7 +105,12 @@ def load(name: str, root: Path | None = None) -> Model:
             raise ValueError(f"config {name}: {key}={raw[key]!r}; the "
                              f"benchmark's reference knows only {want!r}")
     emb = raw["embedding"]
-    if emb["kind"] == "qr":
+    if emb["kind"] == "dense":
+        if set(emb) != {"kind"}:
+            raise ValueError(f"config {name}: dense tables take no compression "
+                             f"keys, got {sorted(set(emb) - {'kind'})}")
+        extra = {}
+    elif emb["kind"] == "qr":
         if emb.get("reconstruction", "add") != "add":
             raise ValueError(f"config {name}: only additive QR is supported")
         extra = {"collision": emb["collision"]}
@@ -123,7 +140,7 @@ def check_program_config(m: Model, cfg) -> None:
     }
     if m.kind == "qr":
         want["qr_collision"] = m.collision
-    else:
+    elif m.kind == "tt":
         want["tt_rank"] = m.rank
     got = {k: getattr(cfg, k) for k in want}
     if got != want:
@@ -143,7 +160,6 @@ def _program_tt_spec(cfg):
     return dlrm.make_bags(cfg)[0].emb.tt_spec
 
 
-@functools.partial(jax.jit, static_argnames=("m",))
 def _make(key, m: Model):
     kb, kt, ke = jax.random.split(key, 3)
     params = {}
@@ -156,7 +172,9 @@ def _make(key, m: Model):
             for kk, (i, o) in zip(keys, dims)
         ]
     shapes = m.table_shapes()
-    if m.kind == "qr":
+    if m.kind == "dense":
+        scales = {"table": m.dim ** -0.5}
+    elif m.kind == "qr":
         scales = {"q": m.dim ** -0.5, "r": m.dim ** -0.5}
     else:
         # a reconstructed entry sums rank**2 products of three core entries
@@ -173,8 +191,30 @@ def _make(key, m: Model):
     return params
 
 
-def make_params(m: Model, seed: int):
-    """Every weight of ``m`` from ``seed``, on the device, in one jitted call."""
+_make_on_default_device = jax.jit(_make, static_argnames=("m",))
+
+
+@functools.cache
+def _make_placed(m: Model, devices: tuple):
+    """``_make`` jitted to write its output in place: table rows split over
+    ``devices`` on axis ``ROW_AXIS``, everything else replicated."""
+    mesh = Mesh(np.asarray(devices), (ROW_AXIS,))
+    rows, whole = NamedSharding(mesh, P(ROW_AXIS, None)), NamedSharding(mesh, P())
+    mlp = {part: [{"w": whole, "b": whole} for _ in dims]
+           for part, dims in m.mlp_dims().items()}
+    tables = [{leaf: rows for leaf in m.table_shapes()}
+              for _ in range(m.num_tables)]
+    return jax.jit(functools.partial(_make, m=m),
+                   out_shardings={**mlp, "tables": tables})
+
+
+def make_params(m: Model, seed: int, devices=()):
+    """Every weight of ``m`` from ``seed``, on the device, in one jitted call:
+    on the default device, or placed on ``devices``, the cell's chips, where
+    there are more than one."""
     from bench.traffic.generator import base_key
 
-    return _make(jax.random.fold_in(base_key(seed), 0x5EED), m)
+    key = jax.random.fold_in(base_key(seed), 0x5EED)
+    if len(devices) <= 1:
+        return _make_on_default_device(key, m)
+    return _make_placed(m, tuple(devices))(key)

@@ -22,8 +22,10 @@ def peaks(device_kind: str) -> dict:
                        f"known: {sorted(PEAKS)}") from None
 
 
-def least_time_s(flops: float, nbytes: float, pk: dict) -> tuple[float, str]:
-    """The roofline's least time for the work, and which bound sets it."""
-    t_flops = flops / pk["flops"]
-    t_bytes = nbytes / pk["hbm_bytes_per_s"]
+def least_time_s(flops: float, nbytes: float, pk: dict,
+                 chips: int = 1) -> tuple[float, str]:
+    """The roofline's least time for the work on ``chips`` chips of peaks
+    ``pk`` (their summed rates), and which bound sets it."""
+    t_flops = flops / (pk["flops"] * chips)
+    t_bytes = nbytes / (pk["hbm_bytes_per_s"] * chips)
     return (t_bytes, "bytes") if t_bytes >= t_flops else (t_flops, "flops")

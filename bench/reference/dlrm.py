@@ -6,14 +6,18 @@ embedding rows; the pairwise dot products of the 27 feature vectors (the
 bottom output first, upper triangle, row-major) concatenated after the bottom
 output; the top MLP (ReLU between layers, linear last) gives one logit.
 
-Embedding rows are rebuilt from the compressed tables:
+Embedding rows are read from a dense table (``row(i) = table[i]``), or
+rebuilt from the compressed tables:
 - QR trick (Shi et al., 2020), additive: ``row(i) = Q[i // c] + R[i % c]``;
 - TT-Rec (Yin et al., 2021): ``i = (i1*v2 + i2)*v3 + i3`` and
   ``row(i)[a,b,c] = sum_{p,q} G1[i1][a,p] G2[i2][p,b,q] G3[i3][q,c]``, with
   the cores stored flat as ``(d1, r)``, ``(r, d2, r)`` and ``(r, d3)``.
 
 Nothing here imports the program.  Tables and chunks of bags are taken one
-at a time, so a batch needs the gathered rows of one chunk at once.
+at a time, so a batch needs the gathered rows of one chunk at once.  Dense
+tables are too large to stack: each is pooled by a jitted call of its own,
+on the devices that hold it, so the reference adds one table's gathered rows
+to the weights and not a second copy of them.
 
 Two controls stand in for the program to show that the comparison fails a
 path one step lower in precision than the configuration states:
@@ -67,6 +71,8 @@ def _tables(stacked, precision):
 
 def _bag_rows(tab, ids, m):
     """Rebuilt embedding rows (..., dim) of the ids of one table."""
+    if m.kind == "dense":
+        return tab["table"][ids]
     if m.kind == "qr":
         qi, ri = qr_split(ids, m.collision)
         return tab["q"][qi] + tab["r"][ri]
@@ -82,13 +88,20 @@ def _bag_rows(tab, ids, m):
 CHUNK = 256     # bags rebuilt at once: a TT chunk gathers 64 MiB of core rows
 
 
+def _chunk(b: int) -> int:
+    return CHUNK if b % CHUNK == 0 else b
+
+
 def pooled(tables, idx, m, precision="f32"):
     """(B, T, K) ids -> (B, T, dim) summed embedding rows, one table and
     one chunk of bags at a time."""
+    if m.kind == "dense":
+        return jnp.stack([_table_pooled(t, idx[:, i], m, precision)
+                          for i, t in enumerate(tables)], axis=1)
     stacked, dt = _tables({k: jnp.stack([t[k] for t in tables])
                            for k in tables[0]}, precision)
     b, _, k = idx.shape
-    ch = CHUNK if b % CHUNK == 0 else b
+    ch = _chunk(b)
     per_table = jnp.moveaxis(idx, 1, 0).reshape(m.num_tables, b // ch, ch, k)
 
     def one(_, xs):
@@ -99,6 +112,18 @@ def pooled(tables, idx, m, precision="f32"):
 
     _, out = jax.lax.scan(one, None, (stacked, per_table))
     return jnp.moveaxis(out, 0, 1).astype(jnp.float32)      # (B, T, dim)
+
+
+@functools.partial(jax.jit, static_argnames=("m", "precision"))
+def _table_pooled(tab, ids, m, precision):
+    """One table's (B, K) ids -> (B, dim) summed rows, a chunk of bags at a
+    time."""
+    tab, dt = _tables(tab, precision)
+    b, k = ids.shape
+    ch = _chunk(b)
+    out = jax.lax.map(lambda c: _bag_rows(tab, c, m).sum(axis=1, dtype=dt),
+                      ids.reshape(b // ch, ch, k))
+    return out.reshape(b, m.dim).astype(jnp.float32)
 
 
 def _mlp(layers, x, precision, *, last_linear):
@@ -118,14 +143,21 @@ def interaction(bottom, pooled_, precision="f32"):
     return gram[:, iu, ju]
 
 
-@functools.partial(jax.jit, static_argnames=("m", "precision"))
-def _forward(params, dense, idx, m, precision):
+def _logits(params, dense, emb, precision):
     bottom = _mlp(params["bottom"], dense, precision, last_linear=False)
-    emb = pooled(params["tables"], idx, m, precision)
     z = interaction(bottom, emb, precision)
     top = _mlp(params["top"], jnp.concatenate([bottom, z], axis=-1), precision,
                last_linear=True)
-    return top[:, 0], emb
+    return top[:, 0]
+
+
+@functools.partial(jax.jit, static_argnames=("m", "precision"))
+def _forward(params, dense, idx, m, precision):
+    emb = pooled(params["tables"], idx, m, precision)
+    return _logits(params, dense, emb, precision), emb
+
+
+_head = jax.jit(_logits, static_argnames=("precision",))
 
 
 def forward_with_pooled(params, dense, idx, m, precision="f32"):
@@ -134,6 +166,9 @@ def forward_with_pooled(params, dense, idx, m, precision="f32"):
     if precision not in PRECISIONS:
         raise ValueError(f"precision must be one of {PRECISIONS}")
     with jax.default_matmul_precision("highest"):
+        if m.kind == "dense":
+            emb = pooled(params["tables"], idx, m, precision)
+            return _head(params, dense, emb, precision), emb
         return _forward(params, dense, idx, m, precision)
 
 

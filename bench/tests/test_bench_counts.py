@@ -19,7 +19,7 @@ def _model(name):
 
 
 @pytest.mark.parametrize("name", ["dlrm-qr", "dlrm-tt", "dlrm-qr-smoke",
-                                  "dlrm-tt-smoke"])
+                                  "dlrm-tt-smoke", "dlrm-dense-smoke"])
 def test_big_rows_match_the_program(name):
     from repro.configs import registry
     from repro.engine import big_rows
@@ -32,12 +32,15 @@ def test_big_rows_match_the_program(name):
     ids = np.random.default_rng(0).integers(0, m.vocab_per_table, (64, m.pooling))
     if m.kind == "qr":
         ours = np.asarray(reference.qr_split(ids, m.collision)[0])
-    else:
+    elif m.kind == "tt":
         ours = np.asarray(reference.tt_split(ids, *m.vocab_factors[1:])[1])
+    else:
+        ours = ids                      # a dense table's row is the id
     np.testing.assert_array_equal(ours, big_rows(ids.astype(np.int32), emb))
 
 
-@pytest.mark.parametrize("name", ["dlrm-qr-smoke", "dlrm-tt-smoke"])
+@pytest.mark.parametrize("name", ["dlrm-qr-smoke", "dlrm-tt-smoke",
+                                  "dlrm-dense-smoke"])
 def test_weights_have_the_program_layout(name):
     from repro.configs import registry
     from repro.models import dlrm
@@ -68,6 +71,39 @@ def test_program_config_mismatch_is_refused(tmp_path):
 
     with pytest.raises(ValueError, match="differs"):
         model_mod.check_program_config(m, registry.get_dlrm(m.registry_id))
+
+
+@pytest.mark.parametrize("registry_id, ok", [
+    ("dlrm-dense-smoke", True), ("dlrm-qr-smoke", False),
+    ("dlrm-dense", False)])
+def test_dense_config_against_the_registry(tmp_path, registry_id, ok):
+    import json
+
+    from repro.configs import registry
+
+    raw = json.loads((DATA / "dlrm-dense-smoke.json").read_text())
+    raw["registry_id"] = registry_id
+    (tmp_path / "d.json").write_text(json.dumps(raw))
+    m = model_mod.load("d", tmp_path)
+    assert (m.kind, m.table_shapes()) == ("dense", {"table": (4096, 32)})
+    if ok:
+        model_mod.check_program_config(m, registry.get_dlrm(registry_id))
+    else:
+        with pytest.raises(ValueError, match="differs"):
+            model_mod.check_program_config(m, registry.get_dlrm(registry_id))
+
+
+@pytest.mark.parametrize("change, match", [
+    ({"embedding": {"kind": "dense", "collision": 8}}, "no compression keys"),
+    ({"embedding": {"kind": "hashed"}}, "unknown embedding kind"),
+    ({"param_dtype": "bfloat16"}, "knows only")])
+def test_malformed_dense_config_is_refused(tmp_path, change, match):
+    import json
+
+    raw = json.loads((DATA / "dlrm-dense-smoke.json").read_text())
+    (tmp_path / "bad.json").write_text(json.dumps({**raw, **change}))
+    with pytest.raises(ValueError, match=match):
+        model_mod.load("bad", tmp_path)
 
 
 # Two tables, two bags, three ids a bag, at the QR smoke shapes (collision 8,
@@ -102,6 +138,42 @@ def test_tt_gather_counts_by_hand():
     right = n23 * 2 * r * d2 * r * d3 + nid * 2 * d1 * r * d2 * d3
     assert (left, right) == (6 * 512 + 7 * 256, 7 * 256 + 7 * 256)
     assert c["flops"] == min(left, right) + 2 * 2 * 2 * 32
+
+
+def test_dense_gather_counts_by_hand():
+    m = _model("dlrm-dense-smoke")
+    c = counts.gather_counts(IDS, m)
+    # table 0 ids {0,1,9,17}, table 1 ids {8,100,4095}: 7 rows read, none rebuilt
+    assert c["bytes"] == 7 * 32 * 4 + IDS.size * 4 + 2 * 2 * 32 * 4
+    assert c["flops"] == 2 * 2 * 2 * 32
+    assert c["pooled_bytes"] == 2 * 2 * 32 * 4
+
+
+def test_counts_of_an_unknown_kind_raise():
+    import dataclasses
+
+    m = dataclasses.replace(_model("dlrm-dense-smoke"), kind="hashed")
+    with pytest.raises(ValueError, match="no counts"):
+        counts.gather_counts(IDS, m)
+
+
+def test_dense_reference_is_a_lookup_and_sum():
+    m = _model("dlrm-dense-smoke")
+    params = model_mod.make_params(m, 2**40 + 9)
+    rng = np.random.default_rng(4)
+    idx = rng.integers(0, m.vocab_per_table, (16, m.num_tables, m.pooling))
+    dense = rng.standard_normal((16, m.num_dense)).astype(np.float32)
+    logits, pooled = reference.forward_with_pooled(params, dense, idx, m)
+    want = np.stack([np.asarray(t["table"], np.float64)[idx[:, i]].sum(axis=1)
+                     for i, t in enumerate(params["tables"])], axis=1)
+    assert pooled.shape == want.shape == (16, m.num_tables, m.dim)
+    np.testing.assert_allclose(np.asarray(pooled), want, rtol=0,
+                               atol=1e-6 * np.abs(want).max())
+    # the head on the pooled rows, as one program with the lookup
+    with jax.default_matmul_precision("highest"):
+        whole = reference._forward(params, dense, idx, m, "f32")[0]
+    np.testing.assert_allclose(np.asarray(logits), np.asarray(whole),
+                               rtol=1e-6, atol=1e-6)
 
 
 def test_head_and_step_counts_by_hand():

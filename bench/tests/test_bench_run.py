@@ -20,7 +20,8 @@ DATA = harness.BENCH / "tests" / "data"
 DIRS = harness.Dirs(configs=DATA / "configs", traffic=DATA / "traffic",
                     limits=DATA / "limits")
 CELLS = {"qr-smoke": ("dlrm-qr-smoke", "smoke-zipf"),
-         "tt-smoke": ("dlrm-tt-smoke", "smoke-seq")}
+         "tt-smoke": ("dlrm-tt-smoke", "smoke-seq"),
+         "dense-smoke": ("dlrm-dense-smoke", "smoke-zipf")}
 SECONDS = 1e-6          # the floor: two window batches, whatever the timing
 
 

@@ -111,3 +111,21 @@ def test_recorded_trace_reduces_to_its_own_numbers():
     idle = sum(v for _, v in s["breakdown"]["idle_gaps"])
     assert idle == pytest.approx(s["window_s"] - s["busy_s"], rel=1e-9)
     assert trace.sync_ns(tr) < lo
+
+
+def test_summary_reads_only_the_cells_chips():
+    tr, spans = RECORDED["trace"], RECORDED["spans"]
+    lo, hi = trace.window_of(spans)
+    # a second chip, busy all through the window, listed before chip 0
+    other = {"ops": [["fusion.99", lo, hi - lo]],
+             "modules": [["jit_other(1)", lo, hi - lo]]}
+    two = {**tr, "devices": {trace.device_plane(1): other, **tr["devices"]}}
+    one = trace.summarize(tr, spans)
+    assert trace.summarize(two, spans, 1) == one
+    assert trace.summarize(two, spans) == one
+    both = trace.summarize(two, spans, 2)
+    assert both["busy_s"] == pytest.approx((one["busy_s"] + (hi - lo) * 1e-9) / 2)
+    assert both["modules"] == one["modules"]
+    assert both["breakdown"] == one["breakdown"]
+    with pytest.raises(ValueError, match="no plane"):
+        trace.summarize(tr, spans, 2)

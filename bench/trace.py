@@ -22,6 +22,11 @@ MODULES_LINE = "XLA Modules"
 DEVICE_PLANE = re.compile(r"^/device:TPU:\d+$")
 
 
+def device_plane(i: int) -> str:
+    """The trace plane of the host's ``i``-th TPU chip."""
+    return f"/device:TPU:{i}"
+
+
 def extract(log_dir: str) -> dict:
     """The device planes and host annotations of the newest trace under
     ``log_dir``."""
@@ -174,13 +179,20 @@ def idle_gaps(dev: dict, spans: list[list], lo: float, hi: float,
     return [[k, v] for k, v in sorted(total.items(), key=lambda kv: -kv[1])[:n]]
 
 
-def summarize(tr: dict, spans: list[list]) -> dict | None:
-    """Window, busy time, module times and breakdown of a reduced trace;
-    None when the trace holds no device plane."""
+def summarize(tr: dict, spans: list[list], chips: int = 1) -> dict | None:
+    """Window, busy time, module times and breakdown of a reduced trace, from
+    the planes of the cell's ``chips`` chips alone: busy time is their mean,
+    module times and the breakdown are chip 0's.  None when the trace holds
+    no device plane."""
     if not tr["devices"]:
         return None
+    names = [device_plane(i) for i in range(chips)]
+    missing = [n for n in names if n not in tr["devices"]]
+    if missing:
+        raise ValueError(f"trace holds no plane {missing}; it has "
+                         f"{sorted(tr['devices'])}")
     lo, hi = window_of(spans)
-    devs = list(tr["devices"].values())
+    devs = [tr["devices"][n] for n in names]
     busy = sum(busy_ns(d["ops"], lo, hi) for d in devs) / len(devs)
     first = devs[0]
     return {
