@@ -15,11 +15,9 @@ arguments (one chip):
   (e) 3 training steps of ``dlrm-qr`` at batch 2048 through
       ``launch/train.py``'s ``build``; the loss must stay finite.
 
-``--four-chip`` runs only the row-sharded path instead: ``dlrm-dense``
-(26.6 GB of f32 tables, 6.7 GB per chip over ``model=4``) created directly
-in its row-sharded layout, one ``serve_2k`` batch through the two-level GnR
-(``EmbeddingEngine.gnr``) compared with the GSPMD baseline, and a forward's
-logits checked finite.
+Tables row-sharded over four chips are served by the normal path
+(``serve_rec --chips 4``) and measured by the benchmark's
+``dense-zipf-2k-4chip`` cell.
 
 Every phase prints its numbers on its own lines.  The last line is
 ``{"ok": true, "device": {...}}``, printed only after every phase passed.
@@ -28,7 +26,6 @@ Exits non-zero without it when JAX finds no TPU, when the repository's
 
 Usage:
     python chip_smoke.py
-    python chip_smoke.py --four-chip
 """
 
 from __future__ import annotations
@@ -213,74 +210,6 @@ def train(arch: str = "dlrm-qr", *, batch: int = TRAIN_BATCH,
         check(bool(np.isfinite(loss)), f"train/{cfg.name}: loss {loss} at step {step + 1}")
 
 
-# ---------------------------------------------------------------------------
-# --four-chip: row-sharded dlrm-dense, two-level GnR vs the GSPMD baseline
-# ---------------------------------------------------------------------------
-
-def four_chip(arch: str = "dlrm-dense", *, batch: int = BATCH,
-              chips: int = 4, interpret=None):
-    import dataclasses
-
-    import jax
-    import jax.numpy as jnp
-    import numpy as np
-    from jax.sharding import NamedSharding, PartitionSpec as P
-
-    from repro import engine as E
-    from repro.configs import registry
-    from repro.data import synthetic
-    from repro.launch.mesh import make_mesh
-    from repro.models import dlrm
-
-    check(len(jax.devices()) == chips, f"need {chips} devices, found {len(jax.devices())}")
-    # f32 compute: the two paths then differ by summation order only
-    cfg = dataclasses.replace(registry.get_dlrm(arch), compute_dtype="float32")
-    mesh = make_mesh((1, chips), ("data", "model"))
-    rows, rep = NamedSharding(mesh, P("model", None)), NamedSharding(mesh, P())
-    shapes = jax.eval_shape(lambda k: dlrm.init_dlrm(k, cfg)[0], jax.random.PRNGKey(0))
-    shard = {**jax.tree.map(lambda _: rep, {k: v for k, v in shapes.items() if k != "tables"}),
-             "tables": jax.tree.map(lambda _: rows, shapes["tables"])}
-    t0 = time.perf_counter()
-    params = jax.jit(lambda k: dlrm.init_dlrm(k, cfg)[0], out_shardings=shard)(
-        jax.random.PRNGKey(SEED))
-    jax.block_until_ready(params)
-    per_chip = sum(s.data.nbytes for t in params["tables"] for v in t.values()
-                   for s in v.addressable_shards if s.device == jax.devices()[0])
-    log(f"[{arch}x{chips}] tables created row-sharded in "
-        f"{time.perf_counter() - t0:.3f}s: {per_chip} bytes on chip 0; "
-        f"peak_bytes_in_use={peak_bytes()}")
-
-    data = synthetic.dlrm_batch(cfg, batch, seed=SEED, step=0, alpha=ALPHA)
-    idx = jax.device_put(data["idx"], NamedSharding(mesh, P("data", None, None)))
-    eng = E.compile(E.plan(E.EngineSpec.from_dlrm(cfg), num_shards=chips))
-    outs = {}
-    for name, fn in (("two_level", eng.gnr(mesh, interpret=interpret)),
-                     ("gspmd_baseline", eng.baseline(mesh))):
-        t0 = time.perf_counter()
-        outs[name] = jax.block_until_ready(fn(params["tables"], idx))
-        t1 = time.perf_counter()
-        jax.block_until_ready(fn(params["tables"], idx))
-        log(f"[{arch}x{chips}/{name}] first call {t1 - t0:.6f}s, "
-            f"second {time.perf_counter() - t1:.6f}s, "
-            f"peak_bytes_in_use={peak_bytes()}")
-    if interpret is None:
-        text = jax.jit(eng.gnr(mesh)).lower(params["tables"], idx).as_text()
-        check("tpu_custom_call" in text, "two-level GnR lowered without the Pallas kernel")
-    got = np.asarray(outs["two_level"], np.float32)
-    want = np.asarray(outs["gspmd_baseline"], np.float32)
-    err = float(np.max(np.abs(got - want)))
-    ok = bool(np.allclose(got, want, rtol=TOL, atol=TOL))
-    log(f"[{arch}x{chips}] two-level GnR vs GSPMD baseline: "
-        f"max_abs_diff={err:.9g} (rtol=atol={TOL}) ok={ok}")
-    check(ok, f"{arch}: two-level GnR disagrees with the GSPMD baseline ({err})")
-    logits = jax.jit(dlrm.forward_from_pooled, static_argnames=("cfg",))(
-        params, data["dense"], outs["two_level"], cfg=cfg)
-    finite = bool(np.isfinite(np.asarray(logits)).all())
-    log(f"[{arch}x{chips}] forward logits finite: {finite} "
-        f"(first {np.asarray(logits)[:4].round(6).tolist()})")
-    check(finite, f"{arch}: non-finite logits")
-
-
 def one_chip() -> None:
     for arch in ("dlrm-qr", "dlrm-tt"):
         cfg, state, params = serve(arch)
@@ -291,9 +220,7 @@ def one_chip() -> None:
 
 def main(argv=None) -> int:
     ap = argparse.ArgumentParser(description=__doc__.splitlines()[0])
-    ap.add_argument("--four-chip", action="store_true",
-                    help="run only the row-sharded dlrm-dense path on 4 chips")
-    args = ap.parse_args(argv)
+    ap.parse_args(argv)
 
     import jax
 
@@ -311,7 +238,7 @@ def main(argv=None) -> int:
     log(f"# device {devices[0].device_kind} x{len(devices)}, "
         f"compile cache {compile_cache.enable()}")
     t0 = time.perf_counter()
-    four_chip() if args.four_chip else one_chip()
+    one_chip()
     log(f"# all phases passed in {time.perf_counter() - t0:.3f}s")
     print(json.dumps({"ok": True, "device": {
         "platform": devices[0].platform, "kind": devices[0].device_kind,

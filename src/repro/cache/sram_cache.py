@@ -64,15 +64,31 @@ class PrefetchScheduler:
     ``value`` — optional (num_rows,) static prefetch value from the intra-GnR
     analyzer, used to break ties between rows with equal in-batch counts
     (rows that historically show more intra-GnR reuse win a slot).
+
+    ``shards`` > 1 splits the rows into contiguous ranges of ``shard_rows``
+    (default: an even split), one a chip of a row-sharded table: each shard
+    has a cache block of its own ``num_slots`` slots holding only rows it
+    owns.  Slots are numbered flat, shard ``k``'s block at
+    ``[k * num_slots, (k + 1) * num_slots)``; ``slot_map`` gives each row's
+    flat slot and ``cache_rows`` the rows of every block in turn.
     """
 
-    def __init__(self, num_rows: int, num_slots: int, value: np.ndarray | None = None):
+    def __init__(self, num_rows: int, num_slots: int, value: np.ndarray | None = None,
+                 *, shards: int = 1, shard_rows: int | None = None):
         if num_slots <= 0:
             raise ValueError("num_slots must be positive")
         self.num_rows = num_rows
-        self.num_slots = min(num_slots, num_rows)
-        self.slot_rows = np.full(self.num_slots, -1, dtype=np.int32)
+        self.shards = shards
+        self.shard_rows = shard_rows or -(-num_rows // shards)
+        if self.shard_rows * shards < num_rows:
+            raise ValueError(f"{shards} shards of {self.shard_rows} rows do "
+                             f"not cover {num_rows} rows")
+        self.num_slots = min(num_slots, self.shard_rows)
+        self.slot_rows = np.full(shards * self.num_slots, -1, dtype=np.int32)
         self.slot_map = np.full(num_rows, -1, dtype=np.int32)
+        # an empty slot gathers its shard's first row (see cache_rows)
+        self._empty_rows = np.repeat(
+            np.arange(shards, dtype=np.int32) * self.shard_rows, self.num_slots)
         if value is not None and value.shape != (num_rows,):
             raise ValueError(f"value must be ({num_rows},), got {value.shape}")
         # normalized to [0, 1): strictly a tiebreak under integer counts
@@ -92,15 +108,31 @@ class PrefetchScheduler:
         return self.update(self.rank(next_idx))
 
     def rank(self, next_idx: np.ndarray) -> np.ndarray:
-        """The rows batch ``t+1`` should find resident, best first.
+        """The rows batch ``t+1`` should find resident, best first (shard by
+        shard when there are several).
 
         Rows are ranked by in-batch access count + analyzer tiebreak; the top
-        ``num_slots`` that the batch touches win residency.
+        ``num_slots`` of each shard that the batch touches win residency.
+        Only the touched rows are ranked: an untouched row never wins, since
+        its key (``value < 1``) is below every touched row's, and ``rows``
+        comes sorted, so the stable sort keeps row order among ties.
         """
-        flat = np.asarray(next_idx).reshape(-1)
-        counts = np.bincount(flat, minlength=self.num_rows)
-        want = np.argsort(-(counts + self.value), kind="stable")[: self.num_slots]
-        return want[counts[want] > 0]                  # never stage untouched rows
+        rows, counts = self._touched(np.asarray(next_idx).reshape(-1))
+        key = -(counts + self.value[rows])
+        cuts = np.searchsorted(
+            rows, np.arange(1, self.shards) * self.shard_rows)
+        return np.concatenate([
+            r[np.argsort(k, kind="stable")[: self.num_slots]]
+            for r, k in zip(np.split(rows, cuts), np.split(key, cuts))])
+
+    def _touched(self, flat: np.ndarray) -> tuple[np.ndarray, np.ndarray]:
+        """The rows ``flat`` touches, sorted, and how often each: by counting
+        where the rows are no more than the accesses, else by sorting."""
+        if self.num_rows <= flat.size:
+            counts = np.bincount(flat, minlength=self.num_rows)
+            rows = np.flatnonzero(counts)
+            return rows, counts[rows]
+        return np.unique(flat, return_counts=True)
 
     def update(self, want: np.ndarray) -> int:
         """Make ``want`` (from ``rank``) the resident set; returns rows DMA'd.
@@ -109,22 +141,32 @@ class PrefetchScheduler:
         staged, which is what makes steady-state Zipf traffic small (the hot
         head barely changes between batches).
         """
-        resident = set(int(r) for r in self.slot_rows if r >= 0)
+        want = np.asarray(want)
+        owner = want // self.shard_rows
+        staged = 0
+        for k in range(self.shards):
+            staged += self._update_block(want[owner == k], k * self.num_slots)
+        return staged
+
+    def _update_block(self, want: np.ndarray, lo: int) -> int:
+        """``update`` for the cache block whose slots start at ``lo``."""
+        block = self.slot_rows[lo:lo + self.num_slots]          # a view
+        resident = set(int(r) for r in block if r >= 0)
         keep = np.array([r for r in want if int(r) in resident], dtype=np.int32)
         stage = np.array([r for r in want if int(r) not in resident], dtype=np.int32)
 
         # evict non-winners, then fill free slots with the staged rows
         keep_set = set(int(r) for r in keep)
         evicted = 0
-        for s, r in enumerate(self.slot_rows):
+        for s, r in enumerate(block):
             if r >= 0 and int(r) not in keep_set:
                 self.slot_map[r] = -1
-                self.slot_rows[s] = -1
+                block[s] = -1
                 evicted += 1
-        free = np.flatnonzero(self.slot_rows < 0)
+        free = np.flatnonzero(block < 0)
         for s, r in zip(free, stage):
-            self.slot_rows[s] = r
-            self.slot_map[r] = s
+            block[s] = r
+            self.slot_map[r] = lo + s
 
         self.stats.staged_rows += int(stage.size)
         self.stats.kept_rows += int(keep.size)
@@ -142,13 +184,14 @@ class PrefetchScheduler:
         return slots
 
     def cache_rows(self) -> np.ndarray:
-        """(num_slots,) row id per slot, clamped so empty slots gather row 0.
+        """(shards * num_slots,) row id per slot; an empty slot gathers its
+        shard's first row (row 0 with one shard).
 
         Feeds the device-side cache-block gather ``table[cache_rows()]`` (the
         staging DMA made visible to jax); the slot map never routes an access
         to an empty slot, so the clamp is unobservable.
         """
-        return np.maximum(self.slot_rows, 0).astype(np.int32)
+        return np.where(self.slot_rows >= 0, self.slot_rows, self._empty_rows)
 
 
 def simulate(

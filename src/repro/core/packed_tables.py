@@ -188,6 +188,21 @@ def build_layout(
     )
 
 
+def shard_layout(layout: PackedLayout, chips: int) -> PackedLayout:
+    """The layout of one chip's share of row-sharded tables: each big
+    subtable's rows split evenly over ``chips`` (the padded row counts divide
+    by any chip count that divides ``ROW_PAD``), the small subtables as they
+    are, and each table's slot budget (the chip's cache block) at most the
+    chip's rows of it, as ``PrefetchScheduler`` caps it."""
+    if any(r % chips for r in layout.rows_per_table):
+        raise ValueError(f"{chips} chips do not divide the tables' rows "
+                         f"{layout.rows_per_table}")
+    rows = tuple(r // chips for r in layout.rows_per_table)
+    return dataclasses.replace(
+        layout, rows_per_table=rows,
+        slot_budgets=tuple(min(b, r) for b, r in zip(layout.slot_budgets, rows)))
+
+
 @functools.lru_cache(maxsize=64)
 def _layout_for(bags: tuple) -> PackedLayout:
     return build_layout(list(bags))
@@ -270,18 +285,22 @@ def _valid_mask(idx: jax.Array, lengths: jax.Array | None) -> jax.Array | None:
 
 
 def pack_indices(
-    idx: jax.Array, layout: PackedLayout, *, lengths: jax.Array | None = None
+    idx: jax.Array, layout: PackedLayout, *, lengths: jax.Array | None = None,
+    keep: jax.Array | None = None,
 ) -> dict:
     """Logical (B, T, K) bag indices -> globally-offset packed streams.
 
     ``lengths`` (B, T) optionally marks ragged bags: positions ``k >=
     lengths[b, t]`` are routed to the zero rows and contribute nothing —
-    empty bags (length 0) pool to exactly zero.
+    empty bags (length 0) pool to exactly zero.  ``keep`` (B, T, K), where
+    given, routes the entries it marks False to the zero rows as well.
     """
     idx = idx.astype(jnp.int32)
     assert idx.shape[-2] == layout.num_tables, (idx.shape, layout.num_tables)
     off = jnp.asarray(layout.row_offsets, jnp.int32)[None, :, None]
     mask = _valid_mask(idx, lengths)
+    if keep is not None:
+        mask = keep if mask is None else mask & keep
 
     if layout.kind == "qr":
         q_idx, r_idx = hashing.qr_decompose(idx, layout.collision)
@@ -317,6 +336,20 @@ def global_slots(slot: jax.Array, layout: PackedLayout) -> jax.Array:
     return jnp.where(slot >= 0, slot + off, -1)
 
 
+def owned_entries(idx: jax.Array, layout: PackedLayout, shard: jax.Array
+                  ) -> tuple[jax.Array, jax.Array]:
+    """Which logical (B, T, K) entries chip ``shard`` owns under the
+    ``shard_layout`` ``layout`` (its big-subtable row lies in the chip's
+    range), and the entries as logical ids of the chip's share: an owned
+    entry's big row becomes local, its QR remainder stays; others read 0."""
+    per = jnp.asarray(layout.rows_per_table, jnp.int32)[None, :, None]
+    if layout.kind == "qr":
+        per = per * layout.collision         # logical ids of a chip's Q rows
+    local = idx.astype(jnp.int32) - shard * per
+    owned = (local >= 0) & (local < per)
+    return jnp.where(owned, local, 0), owned
+
+
 def miss_slots(idx: jax.Array) -> jax.Array:
     """All-miss slot map (the no-cache / mesh configuration)."""
     return jnp.full(idx.shape, -1, jnp.int32)
@@ -339,6 +372,22 @@ def packed_cache_rows(
         np.concatenate(parts) if parts else np.empty((0,), np.int64)
     )
     return total.astype(np.int32)
+
+
+def shard_cache_rows(
+    cache_rows: Sequence[np.ndarray], layout: PackedLayout, chips: int
+) -> np.ndarray:
+    """Per-table scheduler ``cache_rows()`` of row-sharded tables (each
+    ``chips`` blocks of the table's slot budget, of rows the block's chip
+    owns) -> (chips, total_slots) rows of each chip's packed share, under
+    the ``shard_layout`` ``layout``."""
+    parts = []
+    for t, rows in enumerate(cache_rows):
+        n = layout.slot_budgets[t]
+        assert rows.shape == (chips * n,), (rows.shape, chips, n)
+        base = np.arange(chips, dtype=np.int64)[:, None] * layout.rows_per_table[t]
+        parts.append(rows.reshape(chips, n) - base + layout.row_offsets[t])
+    return np.concatenate(parts, axis=1).astype(np.int32)
 
 
 def dummy_cache(layout: PackedLayout, dtype) -> jax.Array:

@@ -21,7 +21,10 @@ repo.  It owns the dispatch that used to be hand-wired per caller:
 * ``cached_lookup`` / ``pack`` / ``serve_gather`` — the batched serving path:
                           prefetch-scheduler slot maps routed through the
                           packed cache block, one jit keyed by the (hashable)
-                          plan.
+                          plan.  Tables row-sharded over a mesh are packed
+                          and gathered chip by chip (each chip its own rows
+                          and cache block) and ``reduce_pooled`` sums the
+                          chips' pooled partials.
 * ``baseline``          — the no-technique GSPMD reference (benchmarks diff
                           against it).
 
@@ -51,20 +54,48 @@ from repro.kernels import ops
 # repeated sessions/benchmark repeats hit jax's compilation cache.
 # ---------------------------------------------------------------------------
 
-@functools.partial(jax.jit, static_argnames=("plan", "interpret"))
+@functools.partial(jax.jit, static_argnames=("plan", "mesh", "interpret"))
 def _serve_gather_jit(packed, idx, slot, cache_rows, plan: EmbeddingPlan,
-                      interpret: bool | None = None):
+                      mesh: Mesh | None = None, interpret: bool | None = None):
     # Trace-time bump: a counter inside a jitted body counts *traces*, not
     # calls, so this is the compiled-program count for the serving dispatch.
     # The online re-planner's runtime-arg swaps must leave it at 1.
     obs.inc("engine/compile/serve_gather")
-    layout = plan.layout
+    if mesh is None:
+        return _gather_pooled(packed, idx, slot, cache_rows, plan, plan.layout,
+                              interpret=interpret)
+    axis = plan.spec.row_axis
+    layout = packed_tables.shard_layout(plan.layout, mesh.shape[axis])
+    budgets = jnp.asarray(layout.slot_budgets, jnp.int32)[None, :, None]
+
+    def local(packed, idx, slot, cache_rows):
+        # every chip walks every entry; those it does not own read its zero
+        # rows, and a slot names a place in the owner's cache block
+        shard = jax.lax.axis_index(axis)
+        idx, owned = packed_tables.owned_entries(idx, layout, shard)
+        slot = jnp.where(owned & (slot >= 0), slot - shard * budgets, -1)
+        return _gather_pooled(packed, idx, slot, cache_rows[0], plan, layout,
+                              keep=owned, interpret=interpret)[None]
+
+    return jax.shard_map(
+        local, mesh=mesh,
+        in_specs=(_packed_specs(plan.layout.kind, axis), P(), P(), P(axis)),
+        out_specs=P(axis), check_vma=False,
+    )(packed, idx, slot, cache_rows)
+
+
+def _gather_pooled(packed, idx, slot, cache_rows, plan: EmbeddingPlan, layout,
+                   *, keep=None, interpret=None):
+    """One chip's megakernel dispatch over the packed buffers of ``layout``."""
     with jax.named_scope("index_pack"):
-        streams = packed_tables.pack_indices(idx, layout)
+        streams = packed_tables.pack_indices(idx, layout, keep=keep)
         streams["slot"] = packed_tables.global_slots(slot, layout)
     # the cache-block gather IS the staging DMA (overlapped on hardware)
     with jax.named_scope("cache_stage"):
-        cache = packed[packed_tables.big_key(layout.kind)][cache_rows]
+        cache = ops.packed_cache_block(
+            packed[packed_tables.big_key(layout.kind)], cache_rows,
+            kind=layout.kind, exec_mode=plan.spec.exec_backend,
+            interpret=interpret)
     pooled = ops.packed_multi_pooled(
         {**packed, "cache": cache}, streams,
         kind=layout.kind, dims=layout.tt_dims, exec_mode=plan.spec.exec_backend,
@@ -74,6 +105,54 @@ def _serve_gather_jit(packed, idx, slot, cache_rows, plan: EmbeddingPlan,
     return pooled * scale[None, :, None].astype(pooled.dtype)
 
 
+@functools.partial(jax.jit, static_argnames=("mesh", "axis"))
+def _reduce_pooled_jit(partials, mesh: Mesh, axis: str):
+    """(chips, B, T, dim) per-chip pooled partials -> their (B, T, dim) sum,
+    whole on every chip: only pooled vectors cross chips, never table rows.
+    A module of its own, so a trace tells the collective from the gather."""
+    return jax.shard_map(
+        lambda p: jax.lax.psum(p[0], axis), mesh=mesh,
+        in_specs=P(axis), out_specs=P(), check_vma=False,
+    )(partials)
+
+
+@functools.partial(jax.jit, static_argnames=("layout", "mesh", "axis"))
+def _pack_sharded_jit(tables, layout, mesh: Mesh, axis: str):
+    """Each chip packs its own row shard of every table (``shard_layout``):
+    no table row moves between chips."""
+    local = packed_tables.shard_layout(layout, mesh.shape[axis])
+    key = packed_tables.big_key(layout.kind)
+    in_specs = [{k: P(axis, None) if k == key else P() for k in t}
+                for t in tables]
+    return jax.shard_map(
+        lambda ts: packed_tables.pack_params(ts, local), mesh=mesh,
+        in_specs=(in_specs,), out_specs=_packed_specs(layout.kind, axis),
+        check_vma=False,
+    )(tables)
+
+
+def _packed_specs(kind: str, axis: str) -> dict:
+    """Packed buffers on a mesh: the streamed big buffer split by chip, the
+    small shared subtables (QR R LUTs) whole on every chip."""
+    if kind == "tt":
+        raise NotImplementedError(
+            "TT tables are not served row-sharded over a mesh: the mesh "
+            "serving path covers dense and QR tables")
+    if kind == "qr":
+        return {"q": P(axis), "r": P()}
+    return {"table": P(axis)}
+
+
+def row_mesh(arr, axis: str) -> Mesh | None:
+    """The mesh ``arr``'s rows are split over on ``axis``, or None when they
+    are not split (a single-device array)."""
+    sh = getattr(arr, "sharding", None)
+    if (isinstance(sh, NamedSharding) and sh.mesh.shape.get(axis, 1) > 1
+            and len(sh.spec) > 0 and sh.spec[0] == axis):
+        return sh.mesh
+    return None
+
+
 class EmbeddingEngine:
     """Executable embedding layer compiled from an ``EmbeddingPlan``."""
 
@@ -81,7 +160,7 @@ class EmbeddingEngine:
         self.plan = plan
         self.spec = plan.spec
         self.bags = list(plan.spec.bags)
-        self._grid_steps: dict[int, int | None] = {}
+        self._grid: dict[int, tuple[int, int, int] | None] = {}
         # telemetry: the active plan's summary rides along with any metrics
         # snapshot taken while this engine serves (repro.obs is a no-op when
         # disabled, so plain compiles pay nothing).
@@ -331,12 +410,27 @@ class EmbeddingEngine:
             out = out / jnp.asarray(bag.pooling, out.dtype)
         return out
 
+    def row_mesh(self, tables: Sequence[dict]) -> Mesh | None:
+        """The mesh the tables' big subtables are row-sharded over (on the
+        spec's row axis), or None for single-device tables."""
+        key = packed_tables.big_key(self.plan.kind)
+        return row_mesh(tables[0][key], self.spec.row_axis)
+
     def pack(self, tables: Sequence[dict]) -> dict:
-        """Concatenate per-table params into the packed megakernel buffers."""
+        """Concatenate per-table params into the packed megakernel buffers.
+
+        Tables row-sharded over a mesh (:meth:`row_mesh`) are packed chip by
+        chip, each chip's own rows into its share of the buffer; the small
+        shared subtables are packed whole on every chip.
+        """
         if not self.plan.packed:
             raise ValueError("plan is not packed; no packed buffers to build")
         obs.inc("engine/dispatch/pack")
-        return packed_tables.pack_params(tables, self.plan.layout)
+        mesh = self.row_mesh(tables)
+        if mesh is None:
+            return packed_tables.pack_params(tables, self.plan.layout)
+        return _pack_sharded_jit(list(tables), self.plan.layout, mesh,
+                                 self.spec.row_axis)
 
     def serve_gather(self, packed, idx, slot, cache_rows):
         """One megakernel dispatch for a whole batch's embedding layer.
@@ -344,36 +438,63 @@ class EmbeddingEngine:
         ``packed`` from :meth:`pack`; ``idx`` (B, T, K) logical indices;
         ``slot`` (B, T, K) per-table scheduler slots (-1 = miss);
         ``cache_rows`` the packed cache block's global rows
-        (``packed_tables.packed_cache_rows`` over the schedulers).  One jit
+        (:meth:`packed_cache_rows` over the schedulers).  One jit
         keyed by the hashable plan — repeat sessions recompile nothing.
+
+        Packed row-sharded over a mesh, every chip runs the megakernel on its
+        own rows and cache block, and the result is the (chips, B, T, dim)
+        per-chip partials, which :meth:`reduce_pooled` sums.
         """
         if not self.plan.packed:
             raise ValueError("plan is not packed; serve_gather needs a layout")
         obs.inc("engine/dispatch/serve_gather")
-        return _serve_gather_jit(packed, idx, slot, cache_rows, self.plan)
+        mesh = row_mesh(packed[packed_tables.big_key(self.plan.kind)],
+                        self.spec.row_axis)
+        return _serve_gather_jit(packed, idx, slot, cache_rows, self.plan,
+                                 mesh=mesh)
 
-    def grid_steps(self, batch: int) -> int | None:
-        """Grid steps of the packed megakernel that ``serve_gather`` runs for
-        a batch of ``batch`` samples, pad bags included (``ops.packed_grid``,
-        so the count follows the kernel's own grid); None where no kernel
-        tile fits the row width."""
-        if batch not in self._grid_steps:
+    def reduce_pooled(self, partials, mesh: Mesh):
+        """:meth:`serve_gather`'s per-chip partials on ``mesh`` -> the batch's
+        (B, T, dim) pooled embeddings, whole on every chip."""
+        return _reduce_pooled_jit(partials, mesh, self.spec.row_axis)
+
+    def grid(self, batch: int) -> tuple[int, int, int] | None:
+        """``(chunk, n_chunks, steps)`` of the packed megakernel that
+        ``serve_gather`` runs (on each chip) for a batch of ``batch``
+        samples, pad bags included (``ops.packed_grid``, so the count follows
+        the kernel's own grid); None where no kernel tile fits the row
+        width."""
+        if batch not in self._grid:
             layout = self.plan.layout
-            grid = ops.packed_grid(
+            self._grid[batch] = ops.packed_grid(
                 layout.kind, batch * layout.num_tables, self.bags[0].pooling,
                 layout.dim, dim_block=self.plan.dim_block,
                 dtype=self.bags[0].emb.param_dtype,
             )
-            self._grid_steps[batch] = None if grid is None else grid[2]
-        return self._grid_steps[batch]
+        return self._grid[batch]
 
-    def packed_cache_rows(self, schedulers) -> "np.ndarray":
-        """Per-table scheduler state -> the packed cache block's global rows."""
+    def grid_steps(self, batch: int) -> int | None:
+        """Grid steps of :meth:`grid`."""
+        grid = self.grid(batch)
+        return None if grid is None else grid[2]
+
+    def walked(self, batch: int) -> int | None:
+        """Entries the megakernel walks (on each chip) for a batch of
+        ``batch`` samples, pad bags included."""
+        grid = self.grid(batch)
+        return None if grid is None else grid[0] * grid[1] * self.bags[0].pooling
+
+    def packed_cache_rows(self, schedulers, chips: int = 1) -> "np.ndarray":
+        """Per-table scheduler state -> the packed cache block's global rows,
+        or with ``chips`` > 1 (schedulers of row-sharded tables) each chip's
+        cache block as rows of its share, (chips, slots)."""
         if not self.plan.packed:
             raise ValueError("plan is not packed; no packed cache block exists")
-        return packed_tables.packed_cache_rows(
-            [s.cache_rows() for s in schedulers], self.plan.layout
-        )
+        rows = [s.cache_rows() for s in schedulers]
+        if chips == 1:
+            return packed_tables.packed_cache_rows(rows, self.plan.layout)
+        return packed_tables.shard_cache_rows(
+            rows, packed_tables.shard_layout(self.plan.layout, chips), chips)
 
     def hot_tiers(self, tables: Sequence[dict]):
         """Duplication-plan hot-tier arrays (uniform pytree, one per table)."""
@@ -381,8 +502,8 @@ class EmbeddingEngine:
             raise ValueError("plan has no duplication plan")
         return SE.make_dup_hot_tiers(tables, self.bags, self.plan.dup)
 
-    def fresh_schedulers(self):
-        return self.plan.fresh_schedulers()
+    def fresh_schedulers(self, chips: int = 1):
+        return self.plan.fresh_schedulers(chips)
 
     def summary(self) -> dict:
         return self.plan.summary()

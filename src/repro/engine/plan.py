@@ -109,15 +109,25 @@ class EmbeddingPlan:
             return tuple(False for _ in self.bags)
         return tuple(t.comm_free for t in self.dup.tables)
 
-    def fresh_schedulers(self) -> list[PrefetchScheduler]:
-        """One prefetch scheduler per table (stateful — fresh per session)."""
+    def fresh_schedulers(self, chips: int = 1) -> list[PrefetchScheduler]:
+        """One prefetch scheduler per table (stateful — fresh per session).
+
+        ``chips`` > 1: the tables' big subtables are row-sharded over that
+        many chips as the packed layout pads them, and each chip gets a cache
+        block of the table's slot budget holding only rows it owns.
+        """
         if not self.has_cache:
             raise ValueError("plan has no cache slots; set spec.cache_slots")
+        if chips > 1 and self.layout is None:
+            raise ValueError("row-sharded schedulers need a packed layout")
         scheds = []
         for t, bag in enumerate(self.bags):
             _name, rows = big_subtable(bag.emb)
             value = self.values[t] if self.values else None
-            scheds.append(PrefetchScheduler(rows, self.slot_budgets[t], value))
+            shard_rows = (self.layout.rows_per_table[t] // chips
+                          if chips > 1 else None)
+            scheds.append(PrefetchScheduler(rows, self.slot_budgets[t], value,
+                                            shards=chips, shard_rows=shard_rows))
         return scheds
 
     def summary(self) -> dict:

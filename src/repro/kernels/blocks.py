@@ -22,6 +22,9 @@ kernels (dense / QR / TT bags, cached or not).
   ``SMEM_STREAM_BYTES`` — one ``pallas_call`` per chunk under a
   ``lax.map``.  A serving batch (2048 samples x 26 tables = 53,248 bags of
   32) is about a hundred chunks.
+* **Staged rows** (``stage_rows``, the prefetch cache block): an XLA
+  gather of rows of a ``row_view`` buffer first copies the whole buffer
+  into another tiling, so the kernel copies each row itself.
 * **Bag blocks** (``run_bag_blocks``, the dense and QR row gathers): one
   grid step serves a block of ``G`` bags.  The table stays in HBM
   (``memory_space=pl.ANY``) and the step copies each missed row itself, all
@@ -301,3 +304,50 @@ def run_bag_blocks(streams, table, cache, luts=(), *, dim: int, bd: int,
     )
     return _over_chunks(call, streams, (row_view(table), cache, *luts),
                         chunk, n, dim)
+
+
+STAGE_ROWS_A_STEP = 512
+
+
+def _stage_kernel(rows_ref, table_ref, out_ref, sem, *, g):
+    base = pl.program_id(0) * g
+
+    def start(i, c):
+        pltpu.make_async_copy(table_ref.at[rows_ref[base + i]],
+                              out_ref.at[pl.ds(i, 1)], sem).start()
+        return c
+
+    def wait(i, c):
+        pltpu.make_async_copy(table_ref.at[0], out_ref.at[pl.ds(0, 1)],
+                              sem).wait()
+        return c
+
+    jax.lax.fori_loop(0, g, start, 0)
+    jax.lax.fori_loop(0, g, wait, 0)
+
+
+def stage_rows(table, rows, *, interpret: bool,
+               name: str = "cache_stage") -> jax.Array:
+    """``table[rows]`` as (n, dim) for a 32-bit ``(rows, 1, dim)`` buffer
+    left in HBM: each row one copy into the output block, all of a step's
+    ``STAGE_ROWS_A_STEP`` copies in flight at once.  ``rows`` are
+    scalar-prefetched (SMEM), so a few tens of thousands at most."""
+    table = row_view(table)
+    n, dim = rows.shape[0], table.shape[-1]
+    g = min(STAGE_ROWS_A_STEP, -(-n // 8) * 8)
+    steps = -(-n // g)
+    rows = jnp.pad(rows.astype(jnp.int32), (0, steps * g - n))
+    out = pl.pallas_call(
+        functools.partial(_stage_kernel, g=g),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1, grid=(steps,),
+            in_specs=[pl.BlockSpec(memory_space=pl.ANY)],
+            out_specs=pl.BlockSpec((g, dim), lambda b, *_: (b, 0)),
+            scratch_shapes=[pltpu.SemaphoreType.DMA(())],
+        ),
+        out_shape=jax.ShapeDtypeStruct((steps * g, dim), table.dtype),
+        interpret=interpret,
+        name=name,
+    )(rows, table)
+    return out[:n]
+

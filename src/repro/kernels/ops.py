@@ -22,6 +22,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.kernels import blocks
 from repro.kernels import cached_gather as _cg
 from repro.kernels import gnr_bag as _gnr
 from repro.kernels import qr_gather as _qr
@@ -494,6 +495,15 @@ def packed_grid(kind: str, bags: int, k_steps: int, dim: int, *,
     return grid(bags, k_steps, len(PACKED_STREAMS[kind]), dim, bd)
 
 
+def _use_kernel(exec_mode: str, interpret: bool | None) -> bool:
+    """Whether a packed dispatch runs its kernel (see ``packed_multi_pooled``)."""
+    return {
+        "auto": bool(interpret) or jax.default_backend() == "tpu",
+        "kernel": True,
+        "jnp": False,
+    }[exec_mode]
+
+
 def packed_multi_pooled(
     params: dict,
     streams: dict,
@@ -522,11 +532,7 @@ def packed_multi_pooled(
     elsewhere; an explicit ``False`` with ``exec_mode="kernel"`` compiles it
     whatever the backend (the described-chip compile tests).
     """
-    use_kernel = {
-        "auto": bool(interpret) or jax.default_backend() == "tpu",
-        "kernel": True,
-        "jnp": False,
-    }[exec_mode]
+    use_kernel = _use_kernel(exec_mode, interpret)
     if kind not in PACKED_STREAMS:
         raise ValueError(f"packed_multi_pooled: unsupported kind {kind!r}")
     if not use_kernel:                       # the oracles take flat rows
@@ -547,6 +553,22 @@ def packed_multi_pooled(
     if use_kernel:
         return _packed_dense_diff(*args, interp, dim_block)
     return ref.packed_bag_ref(*args)
+
+
+def packed_cache_block(big: jax.Array, rows: jax.Array, *, kind: str,
+                       exec_mode: str = "auto",
+                       interpret: bool | None = None) -> jax.Array:
+    """The packed cache block ``big[rows]``: the prefetch cache's staging
+    DMA.  Where the block megakernel serves (dense and QR rows of 32 bits,
+    on the kernel path as ``packed_multi_pooled`` decides), a kernel copies
+    the rows out of the buffer's row view (``blocks.stage_rows``): XLA's
+    gather would first copy the whole buffer into another tiling.  Elsewhere
+    XLA's gather."""
+    use_kernel = _use_kernel(exec_mode, interpret)
+    if use_kernel and kind != "tt" and _cg.bag_blocks(big.dtype):
+        interp = _interpret_default() if interpret is None else bool(interpret)
+        return blocks.stage_rows(big, rows, interpret=interp)
+    return big[rows]
 
 
 def gnr_pooled_dense(

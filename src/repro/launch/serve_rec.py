@@ -57,10 +57,12 @@ import time
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from repro import engine as engine_mod
 from repro import obs
 from repro.configs import registry
+from repro.core import packed_tables
 from repro.data import synthetic
 from repro.engine import EngineSpec, big_rows, big_subtable  # noqa: F401 (re-export)
 from repro.launch import compile_cache
@@ -117,8 +119,8 @@ class ServeState:
     def slot_budgets(self) -> list[int]:
         return list(self.eplan.slot_budgets)
 
-    def fresh_schedulers(self):
-        return self.engine.fresh_schedulers()
+    def fresh_schedulers(self, chips: int = 1):
+        return self.engine.fresh_schedulers(chips)
 
 
 def build_serve_state(cfg, *, shards: int, alpha: float, seed: int,
@@ -152,6 +154,31 @@ def build_serve_state(cfg, *, shards: int, alpha: float, seed: int,
                       predicted_s=predicted_s, drift=drift)
 
 
+def init_params(cfg, seed: int, chips: int = 1) -> dict:
+    """The DLRM's weights from ``seed``.  With ``chips`` > 1 they are made in
+    place on ``Mesh(devices[:chips], (row_axis,))``: every table's big
+    subtable (dense table, QR quotient table) split by rows over the chips,
+    everything else whole on each chip — the layout ``run_pipeline`` serves
+    row-sharded."""
+    key = jax.random.PRNGKey(seed)
+    if chips == 1:
+        return dlrm.init_dlrm(key, cfg)[0]
+    devices = jax.devices()
+    if len(devices) < chips:
+        raise ValueError(f"--chips {chips}: JAX finds {len(devices)} devices")
+    axis = EngineSpec.row_axis
+    mesh = Mesh(np.asarray(devices[:chips]), (axis,))
+    rows, whole = NamedSharding(mesh, P(axis, None)), NamedSharding(mesh, P())
+    big = packed_tables.big_key(cfg.embedding_kind)
+    make = lambda k: dlrm.init_dlrm(k, cfg)[0]  # noqa: E731
+    shapes = jax.eval_shape(make, key)
+    shard = {**jax.tree.map(lambda _: whole,
+                            {k: v for k, v in shapes.items() if k != "tables"}),
+             "tables": [{k: rows if k == big else whole for k in t}
+                        for t in shapes["tables"]]}
+    return jax.jit(make, out_shardings=shard)(key)
+
+
 # The interaction + MLP head.  The pooled buffer is not donated: no output
 # has its (B, T, dim) shape, so the TPU compiler finds no use for it and only
 # warns ("Some donated buffers were not usable").
@@ -163,7 +190,8 @@ def _head_jit(params, dense, pooled, cfg):
 def make_packed_gather(params, state: ServeState):
     """One jitted megakernel dispatch for a whole batch's embedding layer.
 
-    Packs the tables once (device-side); per batch the caller passes the
+    Packs the tables once (device-side; tables row-sharded over a mesh are
+    packed chip by chip); per batch the caller passes the
     logical indices, the per-table local slot maps, and the scheduler's packed
     cache rows — the cache-block gather ``big[cache_rows]`` *is* the staging
     DMA, overlapped (on hardware) with the previous batch.  The dispatch is
@@ -214,14 +242,24 @@ def run_pipeline(cfg, *, batch: int = 16, batches: int = 6, alpha: float = 1.05,
     table's ``rank`` in a ``cache_rank`` span, then every table's
     ``update`` in a ``cache_update`` span (the tables are independent, so
     the slots are those of table-by-table ``prefetch``); ``dispatch`` spans
-    carry the megakernel's ``grid_steps``.
+    carry the megakernel's ``grid_steps``, the ``chips`` it runs on, the
+    entries a chip's kernel ``walked`` (pad bags included) and the entries
+    the busiest chip ``owned``.
+
+    Tables row-sharded over a mesh (``init_params`` with ``chips`` > 1)
+    are served on its chips: each chip gathers from its own rows and cache
+    block, and a ``reduce`` span enqueues the sum of the chips' pooled
+    partials before the head.
     """
     if params is None:
         params, _ = dlrm.init_dlrm(jax.random.PRNGKey(seed), cfg)
     if state is None:
         state = build_serve_state(cfg, shards=shards, alpha=alpha, seed=seed)
     bags = state.bags
-    scheds = state.fresh_schedulers()    # per-run cache state
+    eng = state.engine
+    mesh = eng.row_mesh(params["tables"])
+    chips = 1 if mesh is None else mesh.shape[eng.spec.row_axis]
+    scheds = state.fresh_schedulers(chips)    # per-run cache state
     emb = bags[0].emb
 
     data = [
@@ -236,7 +274,21 @@ def run_pipeline(cfg, *, batch: int = 16, batches: int = 6, alpha: float = 1.05,
     ]                                          # (B, T, K) big-subtable rows
 
     gather = make_packed_gather(params, state)
-    grid_steps = state.engine.grid_steps(batch)
+    grid_steps, walked = eng.grid_steps(batch), eng.walked(batch)
+    if mesh is None:
+        owned = [idx.size for idx in idx_np]
+        put = jnp.asarray
+        put_rows = jnp.asarray
+    else:
+        # entries the busiest chip owns: its share of each big subtable's
+        # rows is the shard layout's (uniform over a packed set)
+        rps = packed_tables.shard_layout(eng.plan.layout, chips).rows_per_table[0]
+        owned = [int(np.bincount((r // rps).ravel(), minlength=chips).max())
+                 for r in rows_np]
+        whole = NamedSharding(mesh, P())
+        by_chip = NamedSharding(mesh, P(eng.spec.row_axis))
+        put = functools.partial(jax.device_put, device=whole)
+        put_rows = functools.partial(jax.device_put, device=by_chip)
 
     def head(params, dense, pooled):
         return _head_jit(params, dense, pooled, cfg)
@@ -262,12 +314,15 @@ def run_pipeline(cfg, *, batch: int = 16, batches: int = 6, alpha: float = 1.05,
                  for i in range(cfg.num_tables)],
                 axis=1,
             )
-            cache_rows = state.engine.packed_cache_rows(scheds)
+            cache_rows = eng.packed_cache_rows(scheds, chips)
         with obs.span("h2d", batch=t):         # host-to-device index upload
-            args = (jnp.asarray(idx_np[t]), jnp.asarray(slot),
-                    jnp.asarray(cache_rows))
-        with obs.span("dispatch", batch=t, grid_steps=grid_steps):
+            args = (put(idx_np[t]), put(slot), put_rows(cache_rows))
+        with obs.span("dispatch", batch=t, grid_steps=grid_steps, chips=chips,
+                      walked=walked, owned=owned[t]):
             pooled = gather(*args)             # megakernel enqueue
+        if mesh is not None:
+            with obs.span("reduce", batch=t):  # the chips' partials summed
+                pooled = eng.reduce_pooled(pooled, mesh)
         if fence:
             with obs.span("device_compute", batch=t):
                 jax.block_until_ready(pooled)
@@ -491,7 +546,14 @@ def main(argv=None) -> int:
     ap.add_argument("--batches", type=int, default=6)
     ap.add_argument("--alpha", type=float, default=1.05)
     ap.add_argument("--shards", type=int, default=4,
-                    help="modeled row-shard count for the duplication plan")
+                    help="modeled row-shard count for the duplication plan "
+                         "(a planning input only; nothing is placed)")
+    ap.add_argument("--chips", type=int, default=1,
+                    help="serve on this many devices: the tables are made "
+                         "row-sharded over them and each chip gathers from "
+                         "its own rows (dense and QR tables); unlike "
+                         "--shards, which only sizes the modeled "
+                         "duplication plan, this places the weights")
     ap.add_argument("--seed", type=int, default=0)
     ap.add_argument("--mode", default="overlap",
                     choices=["overlap", "sequential", "both"])
@@ -557,6 +619,9 @@ def main(argv=None) -> int:
                              or args.adapt):
         ap.error("--profile-dir profiles the unfenced pipeline modes: "
                  "drop --trace-out, --report, --frontend and --adapt")
+    if args.chips > 1 and (args.frontend or args.adapt):
+        ap.error("--chips serves through the pipeline modes: drop "
+                 "--frontend and --adapt")
     compile_cache.enable()
 
     telemetry = bool(args.metrics_json or args.trace_out or args.slo
@@ -583,7 +648,7 @@ def main(argv=None) -> int:
     name = f"{args.arch}-smoke" if (args.smoke or args.tiny) else args.arch
     cfg = registry.get_dlrm(name)
     batch = args.batch or (8 if args.tiny else 16)
-    params, _ = dlrm.init_dlrm(jax.random.PRNGKey(args.seed), cfg)
+    params = init_params(cfg, args.seed, args.chips)
     state = build_serve_state(
         cfg, shards=args.shards, alpha=args.alpha, seed=args.seed
     )

@@ -164,6 +164,78 @@ def test_phase_major_rank_update_matches_per_table_prefetch(
             b.slots_for(batch[:, 0])
 
 
+def _full_vocab_rank(sched, idx):
+    """The ranking as it was before only touched rows were ranked: a count
+    and a stable sort over every row of the subtable."""
+    flat = np.asarray(idx).reshape(-1)
+    counts = np.bincount(flat, minlength=sched.num_rows)
+    want = np.argsort(-(counts + sched.value), kind="stable")[: sched.num_slots]
+    return want[counts[want] > 0]
+
+
+def _rank_case(case, rows):
+    rng = np.random.default_rng(7)
+    value = rng.integers(0, 4, rows).astype(np.float64)
+    if case == "zipf":
+        return (rng.zipf(1.2, (256, 32)) - 1) % rows, 900, value
+    if case == "uniform":
+        return rng.integers(0, rows, (256, 32)), 900, value
+    if case == "ties_no_value":
+        return np.repeat(rng.permutation(rows)[:300], 4), 100, None
+    if case == "ties_with_value":
+        return np.repeat(rng.permutation(rows)[:300], 4), 100, value
+    if case == "more_slots_than_touched":
+        return rng.integers(0, rows, (4, 8)), 500, value
+    assert case == "one_row"
+    return np.full((16, 8), rows // 3), 50, value
+
+
+# 3,000 rows: fewer than the 8,192 ids of a zipf batch, ranked by counting;
+# 2,000,000 rows: ranked by sorting the touched rows
+@pytest.mark.parametrize("rows", [3_000, 2_000_000])
+@pytest.mark.parametrize("case", ["zipf", "uniform", "ties_no_value",
+                                  "ties_with_value", "more_slots_than_touched",
+                                  "one_row"])
+def test_touched_row_rank_is_the_full_vocabulary_rank(case, rows):
+    idx, slots, value = _rank_case(case, rows)
+    sched = sram_cache.PrefetchScheduler(rows, slots, value)
+    got = sched.rank(idx)
+    assert np.array_equal(got, _full_vocab_rank(sched, idx))
+    assert got.size == min(slots, np.unique(idx).size)
+
+
+def test_sharded_scheduler_is_one_scheduler_a_shard():
+    """A scheduler over rows split into shards keeps, for each shard, the
+    cache block that a one-shard scheduler over that shard's rows keeps."""
+    rng = np.random.default_rng(3)
+    rows, per, shards, slots = 250, 64, 4, 9       # the last shard is short
+    value = rng.random(rows)
+    sharded = sram_cache.PrefetchScheduler(rows, slots, value, shards=shards,
+                                           shard_rows=per)
+    norm = value / (value.max() + 1.0)
+    alone = []
+    for k in range(shards):
+        a = sram_cache.PrefetchScheduler(min(per, rows - k * per), slots)
+        a.value = norm[k * per:(k + 1) * per]        # the same tiebreak
+        alone.append(a)
+    for _ in range(5):
+        batch = rng.zipf(1.3, size=(12, 5)) % rows
+        sharded.update(sharded.rank(batch))
+        for k, a in enumerate(alone):
+            mine = batch[(batch >= k * per) & (batch < (k + 1) * per)]
+            a.update(a.rank(mine - k * per))
+            block = sharded.slot_rows[k * slots:(k + 1) * slots]
+            assert np.array_equal(np.where(block >= 0, block - k * per, -1),
+                                  a.slot_rows)
+        slot = sharded.slots_for(batch)
+        owner, hit = batch // per, slot >= 0
+        assert np.all(slot[hit] // slots == owner[hit])
+        assert np.array_equal(sharded.cache_rows()[slot[hit]], batch[hit])
+    empty = sharded.slot_rows < 0
+    assert np.array_equal(sharded.cache_rows()[empty],
+                          (np.flatnonzero(empty) // slots) * per)
+
+
 def test_pinned_cache_has_the_scheduler_phases():
     from repro.adapt.replan import PinnedCache
 

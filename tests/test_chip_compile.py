@@ -20,11 +20,13 @@ import os
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 import pytest
-from jax.sharding import SingleDeviceSharding
+from jax.sharding import Mesh, NamedSharding, PartitionSpec as P, SingleDeviceSharding
 
 from repro import engine as E
 from repro.configs import registry
+from repro.core import packed_tables
 from repro.kernels import cached_gather, gnr_bag, packed_gather, qr_gather, tt_gather
 from repro.models import dlrm
 
@@ -41,21 +43,31 @@ SERVE_BAGS = 2048 * 26     # a serving batch of 2048 samples: 53,248 bags
 
 
 @pytest.fixture(scope="module")
-def one_chip():
+def topo():
     from jax.experimental import topologies
     from jax.experimental.compilation_cache import compilation_cache
 
     os.environ.setdefault("TPU_LOG_DIR", "disabled")
     try:
-        topo = topologies.get_topology_desc(platform="tpu", topology_name="v5e:2x2")
+        desc = topologies.get_topology_desc(platform="tpu", topology_name="v5e:2x2")
     except Exception as e:  # no TPU compiler in this installation
         pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
     was = jax.config.jax_enable_compilation_cache
     jax.config.update("jax_enable_compilation_cache", False)
     compilation_cache.reset_cache()
-    yield SingleDeviceSharding(topo.devices[0])
+    yield desc
     jax.config.update("jax_enable_compilation_cache", was)
     compilation_cache.reset_cache()
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture(scope="module")
+def four_chips(topo):
+    return Mesh(np.asarray(topo.devices[:4]), ("model",))
 
 
 def _sds(sharding, shape, dtype=jnp.float32):
@@ -176,12 +188,60 @@ def test_serve_gather_step_compiles(one_chip, arch):
     fn = functools.partial(E.engine._serve_gather_jit, plan=plan, interpret=False)
     compiled = _compile(fn, packed, idx, idx, rows)
     big = packed[{"qr": "q", "tt": "g2"}[layout.kind]]
-    # The cache-staging gather ``big[cache_rows]`` relayouts a (rows, 1, dim)
-    # row view once per call (XLA gathers from (8, 128) tiles); the TT view
-    # (rows, r, d2*r) is already tiled.
-    copies = 1 if layout.kind == "qr" else 0
-    _table_copies_at_most(compiled, big.size * big.dtype.itemsize, copies)
+    # The QR cache block is staged by a kernel that copies its rows out of
+    # the (rows, 1, dim) row view (an XLA gather would relayout the whole
+    # view once per call); the TT view (rows, r, d2*r) is already tiled.
+    _table_copies_at_most(compiled, big.size * big.dtype.itemsize, 0)
     # stable names for a profile: the megakernel's, and the two scopes
     text = compiled.as_text()
     assert f"%packed_{layout.kind}_bag" in text
     assert "/cache_stage/" in text and "/index_pack/" in text
+
+
+def _collectives(compiled) -> int:
+    text = compiled.as_text()
+    return sum(text.count(f" {op}(") for op in
+               ("all-gather", "all-reduce", "reduce-scatter", "all-to-all",
+                "collective-permute"))
+
+
+def test_row_sharded_dense_serving_compiles_for_four_chips(four_chips):
+    """``dlrm-dense`` (26 x 2M rows x dim 128 in f32, 26.6 GB) row-sharded
+    over four chips: each chip packs its own quarter of every table with no
+    temporaries and no collective, gathers from it with the megakernel and
+    no table row crossing chips, and the pooled partials are summed by one
+    all-reduce in a module of its own.  The chip's tables and its packed
+    share fit its 16 GB."""
+    cfg = registry.get_dlrm("dlrm-dense")
+    plan = E.plan(E.EngineSpec.from_dlrm(cfg, serving=True, duplication=False))
+    layout = plan.layout
+    local = packed_tables.shard_layout(layout, 4)
+    rows, whole = (NamedSharding(four_chips, P("model", None)),
+                   NamedSharding(four_chips, P()))
+    tables = [{"table": _sds(rows, (r, DIM))} for r in layout.rows_per_table]
+    pack = E.engine._pack_sharded_jit.lower(tables, layout, four_chips,
+                                            "model").compile()
+    ma = pack.memory_analysis()
+    share = (local.total_rows + 1) * DIM * 4
+    assert ma.temp_size_in_bytes == 0 and _collectives(pack) == 0
+    assert share <= ma.output_size_in_bytes <= share + 2**20
+
+    packed = {"table": _sds(NamedSharding(four_chips, P("model")),
+                            (4 * (local.total_rows + 1), 1, DIM))}
+    idx = _sds(whole, (2048, cfg.num_tables, cfg.pooling), jnp.int32)
+    cache_rows = _sds(rows, (4, local.total_slots), jnp.int32)
+    gather = _compile(functools.partial(
+        E.engine._serve_gather_jit, plan=plan, mesh=four_chips, interpret=False),
+        packed, idx, idx, cache_rows)
+    assert _collectives(gather) == 0
+    assert gather.memory_analysis().temp_size_in_bytes < 2**26
+    assert "%packed_dense_bag" in gather.as_text()
+
+    partials = _sds(NamedSharding(four_chips, P("model")),
+                    (4, 2048, cfg.num_tables, DIM))
+    reduce = E.engine._reduce_pooled_jit.lower(partials, four_chips,
+                                               "model").compile()
+    assert reduce.as_text().count(" all-reduce(") == 1
+    tables_share = layout.total_rows // 4 * DIM * 4
+    assert tables_share + share < 16e9
+

@@ -230,3 +230,17 @@ def test_flash_fused_grad_matches_reference():
     g2 = jax.grad(lambda a: flash_attention(a, k_, v_, causal=True).sum())(q_)
     np.testing.assert_allclose(np.asarray(g1), np.asarray(g2), rtol=1e-5,
                                atol=1e-6)
+
+
+@pytest.mark.parametrize("rows,n", [(64, 5), (3000, 512), (3000, 1300)])
+def test_stage_rows_is_the_gather(rows, n):
+    """The cache-staging kernel copies each named row of the row view."""
+    from repro.kernels import blocks
+
+    table = jax.random.normal(jax.random.PRNGKey(rows), (rows, 1, 128))
+    idx = jnp.asarray(np.random.default_rng(n).integers(0, rows, n), jnp.int32)
+    got = blocks.stage_rows(table, idx, interpret=True)
+    assert got.shape == (n, 128)
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(table[idx, 0]))
+    via_ops = ops.packed_cache_block(table, idx, kind="qr", exec_mode="kernel")
+    np.testing.assert_array_equal(np.asarray(via_ops), np.asarray(got))
