@@ -469,7 +469,7 @@ class EmbeddingEngine:
             self._grid[batch] = ops.packed_grid(
                 layout.kind, batch * layout.num_tables, self.bags[0].pooling,
                 layout.dim, dim_block=self.plan.dim_block,
-                dtype=self.bags[0].emb.param_dtype,
+                dtype=self.bags[0].emb.param_dtype, dims=layout.tt_dims,
             )
         return self._grid[batch]
 

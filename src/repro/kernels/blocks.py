@@ -32,6 +32,12 @@ kernels (dense / QR / TT bags, cached or not).
   buffer; the K-sum is then K adds of sublane-dense ``(G, bd)`` slabs into
   a ``(G, bd)`` output block.  ``G`` comes from the shapes alone
   (``block_grid``).
+* **TT bag blocks** (``run_tt_blocks``, the packed TT megakernel on 32-bit
+  rows): one grid step serves a block of ``G`` bags the same way, but an
+  entry stages a whole ``(r, d2*r)`` middle-core row (one copy a miss, a
+  cache-row copy a hit) and its two outer-core rows, and the block's ``G*K``
+  accesses are then contracted together (``tt_gather.contract_block``).
+  ``G`` comes from the shapes alone (``tt_block_grid``).
 """
 
 from __future__ import annotations
@@ -51,6 +57,10 @@ SMEM_STREAM_BYTES = 512 * 2**10
 # stay under the 16 MiB a kernel may use by default.
 BLOCK_SCRATCH_BYTES = 2 * 2**20
 VMEM_DEFAULT_LIMIT = 16 * 2**20
+
+# VMEM for a TT bag block's two buffers of middle-core rows: at the
+# published widths (K 32, 8 KiB rows) 8 bags a step.
+TT_BLOCK_SCRATCH_BYTES = 4 * 2**20
 
 
 def row_view(table: jax.Array) -> jax.Array:
@@ -304,6 +314,157 @@ def run_bag_blocks(streams, table, cache, luts=(), *, dim: int, bd: int,
     )
     return _over_chunks(call, streams, (row_view(table), cache, *luts),
                         chunk, n, dim)
+
+
+def _tt_blocking(bags: int, k_steps: int, n_streams: int,
+                 row_bytes: int) -> tuple[int, int, int]:
+    """``(g, chunk, n_chunks)`` of ``run_tt_blocks``: ``g`` bags a step, the
+    largest multiple of 8 whose two ``(K, g)`` buffers of ``row_bytes``
+    middle-core rows fit ``TT_BLOCK_SCRATCH_BYTES`` (fewer for a short
+    stream); chunks as in ``bag_grid``, rounded down to whole blocks."""
+    g = max(8, TT_BLOCK_SCRATCH_BYTES // (2 * k_steps * row_bytes) // 8 * 8)
+    g = min(g, -(-bags // 8) * 8)
+    fit = SMEM_STREAM_BYTES // (4 * k_steps * n_streams) // g * g
+    chunk = min(-(-bags // g) * g, max(g, fit))
+    return g, chunk, -(-bags // chunk)
+
+
+def tt_block_grid(bags: int, k_steps: int, n_streams: int,
+                  row_bytes: int) -> tuple[int, int, int]:
+    """``(chunk, n_chunks, steps)`` of ``run_tt_blocks`` over ``bags`` bags
+    of ``k_steps`` entries in ``n_streams`` index streams, middle-core rows
+    of ``row_bytes``: one grid step per block of bags, pad bags included."""
+    g, chunk, n = _tt_blocking(bags, k_steps, n_streams, row_bytes)
+    return chunk, n, n * (chunk // g)
+
+
+def _tt_block_kernel(i1_ref, i2_ref, i3_ref, slot_ref, g2_ref, cache_ref,
+                     g1_ref, g3_ref, out_ref, rows, outer1, outer3, chat,
+                     sems, misses, *, g, k_steps, contract):
+    # Scratch: two buffers each of the block's middle-core rows (access
+    # n = k * g + bag at rows n*r .. n*r + r), its G1 and G3 rows (row n),
+    # the contraction's scratch, a DMA semaphore and a miss count each.
+    b = pl.program_id(0)
+    rank = cache_ref.shape[1]
+
+    def at(n):
+        return pl.ds(pl.multiple_of(n * rank, rank), rank)
+
+    def copy(row, buf, n):
+        return pltpu.make_async_copy(g2_ref.at[row], rows.at[buf, at(n)],
+                                     sems.at[buf])
+
+    def stage(blk, buf):
+        # Start block ``blk``'s miss copies into buffer ``buf``; copy its
+        # hits from the cache and its outer-core rows, all of them in VMEM.
+        base = blk * (g * k_steps)
+
+        def bag(gi, n_miss):
+            def entry(k, n_miss):
+                pos = base + gi * k_steps + k
+                n = k * g + gi
+                s = slot_ref[pos]
+
+                @pl.when(s < 0)
+                def _miss():
+                    copy(i2_ref[pos], buf, n).start()
+
+                @pl.when(s >= 0)
+                def _hit():
+                    rows[buf, at(n)] = cache_ref[s]
+
+                outer1[buf, pl.ds(n, 1)] = g1_ref[pl.ds(i1_ref[pos], 1), :]
+                outer3[buf, pl.ds(n, 1)] = g3_ref[pl.ds(i3_ref[pos], 1), :]
+                return n_miss + (s < 0).astype(jnp.int32)
+
+            return jax.lax.fori_loop(0, k_steps, entry, n_miss, unroll=True)
+
+        misses[buf] = jax.lax.fori_loop(0, g, bag, jnp.int32(0))
+
+    # Two buffers: block b+1's copies fly while block b is contracted.
+    cur = b % 2
+
+    @pl.when(b == 0)
+    def _first():
+        stage(b, cur)
+
+    @pl.when(b + 1 < pl.num_programs(0))
+    def _next():
+        stage(b + 1, 1 - cur)
+
+    def wait(_, c):
+        copy(0, cur, 0).wait()
+        return c
+
+    jax.lax.fori_loop(0, misses[cur], wait, 0)
+    out_ref[...] = contract(rows.at[cur], outer1.at[cur], outer3.at[cur], chat,
+                           g=g)
+
+
+def run_tt_blocks(streams, g2, cache, g1, g3, *, contract, dim: int,
+                  chat_width: int, interpret: bool,
+                  name: str | None = None) -> jax.Array:
+    """Pooled TT gather, a block of bags per grid step:
+    ``out[b] = contract over k of G1[i1] · (slot >= 0 ? C[slot] : G2[i2]) ·
+    G3[i3]``.
+
+    ``streams`` are the (B, K) int streams ``i1``, ``i2``, ``i3`` and
+    ``slot``, scalar-prefetched flat and chunked to fit SMEM
+    (``tt_block_grid``); pad bags read entry 0 of every stream and are
+    dropped.  ``g2`` (rows, r, d2*r) f32 stays in HBM, and each miss is one
+    row copy; a hit copies its row of the resident ``cache`` (slots, r,
+    d2*r); the outer cores ``g1`` / ``g3`` (rows, ·) f32 are resident too.
+    ``contract(rows, a, c, chat, g=g)`` turns a block's staged rows (refs:
+    the (g*K*r, d2*r) middle-core rows, the (g*K, ·) outer-core rows and a
+    (g*K, ``chat_width``) scratch) into its (g, ``dim``) pooled rows.  Grid
+    ``(blocks,)``.  ``name`` names the ``pallas_call``.  Returns (B, dim)
+    f32.
+    """
+    bsz, k_steps = streams[0].shape
+    rank, width = g2.shape[1], g2.shape[1] * g2.shape[2]
+    assert g2.dtype.itemsize == 4, g2.dtype
+    g, chunk, n = _tt_blocking(bsz, k_steps, len(streams), 4 * width)
+    acc = g * k_steps
+    # The outer cores padded to whole lane tiles: Mosaic slices a buffer of
+    # their rows only at whole tiles.
+    g1, g3 = (jnp.pad(a, ((0, 0), (0, -a.shape[1] % 128))) for a in (g1, g3))
+
+    def whole(a):
+        return pl.BlockSpec(a.shape, lambda b, *_: (0,) * a.ndim,
+                            pipeline_mode=pl.Buffered(1))
+
+    # resident blocks, both row buffers and outer-core row buffers, the
+    # contraction scratch, the output's two blocks, and 4 MiB for Mosaic's
+    # own (the contraction's temporaries)
+    vmem = 4 * (cache.size + g1.size + g3.size
+                + acc * (2 * width + 2 * g1.shape[1] + 2 * g3.shape[1]
+                         + chat_width) + 2 * g * dim) + 4 * 2**20
+    call = pl.pallas_call(
+        functools.partial(_tt_block_kernel, g=g, k_steps=k_steps,
+                          contract=contract),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=len(streams),
+            grid=(chunk // g,),
+            in_specs=[pl.BlockSpec(memory_space=pl.ANY), whole(cache),
+                      whole(g1), whole(g3)],
+            out_specs=pl.BlockSpec((g, dim), lambda b, *_: (b, 0)),
+            scratch_shapes=[
+                pltpu.VMEM((2, acc * rank, width // rank), jnp.float32),
+                pltpu.VMEM((2, acc, g1.shape[1]), jnp.float32),
+                pltpu.VMEM((2, acc, g3.shape[1]), jnp.float32),
+                pltpu.VMEM((acc, chat_width), jnp.float32),
+                pltpu.SemaphoreType.DMA((2,)),
+                pltpu.SMEM((2,), jnp.int32),
+            ],
+        ),
+        out_shape=jax.ShapeDtypeStruct((chunk, dim), jnp.float32),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",),
+            vmem_limit_bytes=vmem if vmem > VMEM_DEFAULT_LIMIT else None),
+        interpret=interpret,
+        name=name,
+    )
+    return _over_chunks(call, streams, (g2, cache, g1, g3), chunk, n, dim)
 
 
 STAGE_ROWS_A_STEP = 512

@@ -27,7 +27,7 @@ from repro.kernels import cached_gather as _cg
 from repro.kernels import gnr_bag as _gnr
 from repro.kernels import qr_gather as _qr
 from repro.kernels import ref
-from repro.kernels.blocks import bag_grid, block_grid
+from repro.kernels.blocks import bag_grid, block_grid, tt_block_grid
 from repro.tune import knobs as _knobs
 
 
@@ -477,22 +477,30 @@ PACKED_STREAMS = {
 
 
 def packed_grid(kind: str, bags: int, k_steps: int, dim: int, *,
-                dim_block: int | None = None,
-                dtype=jnp.float32) -> tuple[int, int, int] | None:
+                dim_block: int | None = None, dtype=jnp.float32,
+                dims: tuple[int, int, int, int] | None = None,
+                ) -> tuple[int, int, int] | None:
     """The grid of the packed megakernel of ``kind`` over ``bags`` bags of
     ``k_steps`` entries, rows of ``dtype``: ``(chunk, n_chunks, steps)``, as
-    the kernel runs it — ``blocks.block_grid`` for dense and QR rows of 32
-    bits, ``blocks.bag_grid`` otherwise; None for a dim that no lane tile
-    fits (the jnp reference runs instead).  The TT kernel takes the whole
-    row as its tile."""
+    the kernel runs it — for rows of 32 bits ``blocks.block_grid`` (dense
+    and QR) or ``blocks.tt_block_grid`` (TT, whose middle-core rows ``dims``
+    = (d1, d2, d3, rank) size), ``blocks.bag_grid`` otherwise; None for a
+    dim that no lane tile fits (the jnp reference runs instead).  The TT
+    kernel takes the whole row as its tile."""
+    n_streams = len(PACKED_STREAMS[kind])
     if kind == "tt":
-        bd = dim if dim % 8 == 0 else None
-    else:
-        bd = _resolve_dim_block(dim, dim_block, True)
+        if dim % 8:
+            return None
+        if _cg.bag_blocks(dtype):
+            _, d2, _, rank = dims
+            row_bytes = rank * d2 * rank * jnp.dtype(dtype).itemsize
+            return tt_block_grid(bags, k_steps, n_streams, row_bytes)
+        return bag_grid(bags, k_steps, n_streams, dim, dim)
+    bd = _resolve_dim_block(dim, dim_block, True)
     if bd is None:
         return None
-    grid = block_grid if kind != "tt" and _cg.bag_blocks(dtype) else bag_grid
-    return grid(bags, k_steps, len(PACKED_STREAMS[kind]), dim, bd)
+    grid = block_grid if _cg.bag_blocks(dtype) else bag_grid
+    return grid(bags, k_steps, n_streams, dim, bd)
 
 
 def _use_kernel(exec_mode: str, interpret: bool | None) -> bool:

@@ -20,11 +20,13 @@ is a single kernel over a **packed** layout:
 * cache-slot routing (the prefetch scheduler) is folded in: ``slot >= 0``
   reads the packed VMEM cache block (per-table slot ranges concatenated),
   ``slot < 0`` fetches the HBM row, so hits issue no HBM traffic;
-* dense and QR rows of 32 bits run a block of bags per grid step, the
-  kernel starting every missed row's copy itself
-  (``blocks.run_bag_blocks``); TT (and 16-bit rows) stream one row per
-  grid step (``blocks.run_bags``), accumulating in fp32 in a VMEM output
-  block revisited across the K steps.
+* rows of 32 bits run a block of bags per grid step, the kernel starting
+  every missed row's copy itself: dense and QR rows through
+  ``blocks.run_bag_blocks``, TT middle-core rows through
+  ``blocks.run_tt_blocks``, which contracts the block's accesses together
+  (``tt_gather.contract_block``); 16-bit rows stream one row per grid step
+  (``blocks.run_bags``), accumulating in fp32 in a VMEM output block
+  revisited across the K steps.
 
 The mesh path calls the same kernels with a 1-row dummy cache and an all-miss
 slot map: masking (non-owned rows, off-shard R positions, ragged bag tails)
@@ -46,7 +48,9 @@ from jax.experimental import pallas as pl
 
 from repro.kernels import cached_gather as _cg
 from repro.kernels import tt_gather as _tt
-from repro.kernels.blocks import accumulate, f32_rows, resident, run_bags, step
+from repro.kernels.blocks import (
+    accumulate, f32_rows, resident, run_bags, run_tt_blocks, step,
+)
 
 # Budget for the VMEM-RESIDENT operands of one dispatch (packed cache block +
 # packed R LUT / TT outer cores; constant index maps keep them live across the
@@ -174,7 +178,9 @@ def packed_tt_bag(
     g2: (total_v2_rows, r*d2*r) packed middle cores (+ zero row), flat or in
     ``tt_gather.g2_view``; cache: the staged G2 rows, same width.
     i1/i2/i3/slot: (G, K) globally offset.  ``dims`` = (d1, d2, d3, rank),
-    static.  Returns (G, d1*d2*d3).
+    static.  32-bit rows run a block of bags per grid step
+    (``blocks.run_tt_blocks``), 16-bit rows one access per step
+    (``blocks.run_bags``).  Returns (G, d1*d2*d3).
     """
     d1, d2, d3, rank = dims
     k_steps = i1.shape[1]
@@ -187,6 +193,15 @@ def packed_tt_bag(
     _check_resident(cache=cache, g1=g1, g3=g3)
     cache = _tt.g2_view(cache, rank).astype(jnp.float32)
     g1, g3 = f32_rows(g1, g1.shape[1]), f32_rows(g3, g3.shape[1])
+    if _cg.bag_blocks(g2.dtype):
+        out = run_tt_blocks(
+            [i1, i2, i3, slot], _tt.g2_view(g2, rank), cache, g1, g3,
+            contract=functools.partial(
+                _tt.contract_block, d1=d1, d2=d2, d3=d3, rank=rank),
+            dim=dim, chat_width=d3 * d2 * rank, interpret=interpret,
+            name="packed_tt_bag",
+        )
+        return out.astype(g2.dtype)
 
     def g2_row(g, k, i1, i2, i3, sl):
         # Streamed G2 row: misses DMA i2's packed row, hits pin row 0.

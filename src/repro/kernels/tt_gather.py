@@ -30,6 +30,11 @@ is streamed already in its matmul shape — ``g2_view``, ``(rows, r, d2*r)``,
 one (r, d2*r) block per row, (8, 128)-aligned at r = 16 — and ``contract``
 unflattens the small outer-core rows and flattens the result with 0/1
 selection matmuls built from iotas (exact at HIGHEST precision).
+
+``contract_block`` is the same contraction for a block of bags at once (the
+packed megakernel's block path, ``blocks.run_tt_blocks``): no per-access
+matmul, the outer-core products on the VPU, and the 0/1 matmuls applied once
+a block.
 """
 
 from __future__ import annotations
@@ -39,6 +44,7 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from repro.kernels.blocks import accumulate, f32_rows, resident, run_bags, step
 
@@ -84,6 +90,59 @@ def contract(a_row, m, c_row, *, d1: int, d2: int, d3: int, rank: int):
     v = _dot(u, _sel((d23, d1 * d23), lambda i, j: j % d23 == i))
     v = v * _sel((d1, d1 * d23), lambda i, j: j // d23 == i)
     return jnp.sum(v, axis=0, keepdims=True)
+
+
+def contract_block(rows, a_ref, c_ref, chat_ref, *, g: int, d1: int, d2: int,
+                   d3: int, rank: int):
+    """The pooled rows of a block of ``g`` bags, every access contracted
+    together: ``(g, d1*d2*d3)`` f32.
+
+    Refs: ``rows`` (N*r, d2*r) holds the block's N = K*g middle-core rows,
+    access ``n = k*g + bag`` at rows ``n*r .. n*r + r``; ``a_ref`` (N, d1*r)
+    and ``c_ref`` (N, r*d3) its flat outer-core rows (lanes past those
+    ignored); ``chat_ref`` (N, d3*d2*r) is scratch.  With ``M_p`` the
+    (N, d2*r) slab of sublane p of every middle row (a strided load) and
+    ``A``, ``C`` the outer rows:
+
+    * ``T_a = sum_p A[:, a*r+p] * M_p`` on the VPU, lanes ``(b, q)``;
+    * ``Chat_c[:, b*r+q] = C[:, q*d3+c]``, one 0/1 matmul for the block;
+    * ``T_a * Chat_c`` summed over each bag's K entries into (g, d2*r);
+    * the sum over q and the flatten to lane ``a*d2*d3 + b*d3 + c``, one 0/1
+      matmul and a lane roll per (a, c), on those g rows alone.
+
+    Every matmul's right side is a fixed 0/1 matrix, at HIGHEST (exact);
+    the rest is f32 arithmetic on the VPU.
+    """
+    r, w, dim = rank, d2 * rank, d1 * d2 * d3
+    n_acc = a_ref.shape[0]
+    chat_ref[...] = _dot(c_ref[...], _sel(
+        (c_ref.shape[1], d3 * w),
+        lambda i, j: (i % d3 == j // w) & (i // d3 == j % r)))
+
+    def pool(k, acc):
+        base = k * g
+        a = a_ref[pl.ds(base, g), :]
+        ch = chat_ref[pl.ds(base, g), :]
+        m = [rows[pl.ds(base * r + p, g, stride=r), :] for p in range(r)]
+        acc = list(acc)
+        for ai in range(d1):
+            t = a[:, ai * r:ai * r + 1] * m[0]
+            for p in range(1, r):
+                t = t + a[:, ai * r + p:ai * r + p + 1] * m[p]
+            for ci in range(d3):
+                acc[ai * d3 + ci] += t * ch[:, ci * w:(ci + 1) * w]
+        return tuple(acc)
+
+    acc = jax.lax.fori_loop(0, n_acc // g, pool,
+                            (jnp.zeros((g, w), jnp.float32),) * (d1 * d3))
+    # sum over q into lane b*d3, then roll to a*d2*d3 + b*d3 + c
+    u = _dot(jnp.concatenate(acc, axis=0),
+             _sel((w, dim), lambda i, j: j == i // r * d3))
+    out = u[:g]
+    for i in range(1, d1 * d3):
+        ai, ci = divmod(i, d3)
+        out = out + pltpu.roll(u[i * g:(i + 1) * g], ai * d2 * d3 + ci, 1)
+    return out
 
 
 def _kernel(

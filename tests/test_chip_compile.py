@@ -138,6 +138,25 @@ def test_packed_tt_bag_compiles(one_chip):
     _table_copies_at_most(compiled, TT_ROWS * rank * d2 * rank * 4, 0)
 
 
+def test_packed_tt_bag_blocks_compile_at_a_serving_batch(one_chip):
+    """f32 TT rows run a block of bags per grid step, the middle cores left
+    in HBM: at a whole serving batch (2048 x 26 bags) the one kernel
+    compiles, reads G2 in place, and its resident cache and outer cores,
+    staging buffers and contraction fit the VMEM limit it raises."""
+    d1, d2, d3, rank = TT_DIMS
+    g2 = _sds(one_chip, (TT_ROWS, rank, d2 * rank))
+    cache = _sds(one_chip, (TT_SLOTS, rank, d2 * rank))
+    g1 = _sds(one_chip, (TT_OUTER, d1 * rank))
+    g3 = _sds(one_chip, (TT_OUTER, rank * d3))
+    stream = _sds(one_chip, (SERVE_BAGS, K), jnp.int32)
+    fn = functools.partial(packed_gather.packed_tt_bag, dims=TT_DIMS, interpret=False)
+    compiled = _compile(fn, g1, g2, g3, cache, stream, stream, stream, stream)
+    _table_copies_at_most(compiled, TT_ROWS * rank * d2 * rank * 4, 0)
+    kernels = [line for line in compiled.as_text().splitlines()
+               if "%packed_tt_bag" in line and "tpu_custom_call" in line]
+    assert len(kernels) == 1
+
+
 @pytest.mark.parametrize("name", [
     "cached_bag", "cached_qr_bag", "gnr_bag", "gnr_bag_dense", "qr_gather", "tt_bag",
 ])

@@ -1,6 +1,7 @@
 """What the serving loop tells a trace: the gather megakernel's grid steps
-(``blocks.block_grid`` for dense and QR, ``blocks.bag_grid`` for TT, pinned
-by hand at the benchmark cells' shapes) and the spans ``run_pipeline`` emits
+(``blocks.block_grid`` for dense and QR, ``blocks.tt_block_grid`` for TT,
+both on 32-bit rows, ``blocks.bag_grid`` for 16-bit rows, pinned by hand at
+the benchmark cells' shapes) and the spans ``run_pipeline`` emits
 around the prefetch schedulers' two phases."""
 
 import collections
@@ -12,7 +13,7 @@ from repro import engine as engine_mod
 from repro import obs
 from repro.configs import registry
 from repro.kernels import ops
-from repro.kernels.blocks import bag_grid, block_grid
+from repro.kernels.blocks import bag_grid, block_grid, tt_block_grid
 
 
 @pytest.fixture(autouse=True)
@@ -61,10 +62,31 @@ def test_block_grid_small_batches_and_lane_tiles():
     assert block_grid(50, 33, 3, 256, 256) == (72, 1, 3)
 
 
+# TT bag blocks of f32 rows at K 32, rank 16, d2 8: a middle-core row is
+# 16 x 128 x 4 B = 8 KiB, so G = 4 MiB / (2 x 32 x 8 KiB) = 8 bags a step.
+# 4 streams fit 1,024 bags a chunk = 128 blocks; 53,248 bags -> 52 chunks x
+# 128 = 6,656 steps; 6,656 bags -> 7 chunks (512 pad bags) x 128 = 896.
+@pytest.mark.parametrize("batch,chunk,n_chunks,steps", [
+    (2048, 1024, 52, 6_656),
+    (256, 1024, 7, 896),
+])
+def test_tt_block_grid_by_hand(batch, chunk, n_chunks, steps):
+    assert tt_block_grid(batch * 26, 32, 4, 8192) == (chunk, n_chunks, steps)
+
+
+def test_tt_block_grid_small_batches_and_rows():
+    assert tt_block_grid(5, 4, 4, 8192) == (8, 1, 1)     # one block, 3 pads
+    # K 33: 4 MiB / (2 x 33 x 8 KiB) < 8, so the floor of 8 bags -> 7 blocks
+    assert tt_block_grid(50, 33, 4, 8192) == (56, 1, 7)
+    # 256 B rows (rank 4, d2 4): G would be 2,048, cut to the 56 bags there are
+    assert tt_block_grid(50, 4, 4, 256) == (56, 1, 1)
+
+
 @pytest.mark.parametrize("arch,batch,steps", [
     ("dlrm-qr", 2048, 840),
     ("dlrm-qr", 256, 105),
-    ("dlrm-tt", 2048, 1_703_936),
+    ("dlrm-tt", 2048, 6_656),
+    ("dlrm-tt", 256, 896),
 ])
 def test_engine_grid_steps_at_published_widths(arch, batch, steps):
     cfg = registry.get_dlrm(arch)
@@ -72,8 +94,9 @@ def test_engine_grid_steps_at_published_widths(arch, batch, steps):
         engine_mod.EngineSpec.from_dlrm(cfg, serving=True), duplication=False)
     eng = engine_mod.compile(engine_mod.plan(spec, num_shards=1))
     assert eng.grid_steps(batch) == steps
-    kind = eng.plan.layout.kind
-    assert ops.packed_grid(kind, batch * 26, 32, 128)[2] == steps
+    layout = eng.plan.layout
+    assert ops.packed_grid(layout.kind, batch * 26, 32, 128,
+                           dims=layout.tt_dims)[2] == steps
 
 
 @pytest.fixture(scope="module")
